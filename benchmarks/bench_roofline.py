@@ -3,9 +3,10 @@
 Section A — the dispatch layer's driver (docs/PERFORMANCE.md): every
 kernel x backend pair reports measured us/call, modelled HBM traffic
 (input bytes x the backend-honest pass count from ``ops.hbm_passes``),
-achieved GB/s and the fraction of the platform's peak bandwidth
-(``launch.roofline.peak_hbm_bandwidth``; override with
-``REPRO_PEAK_BW_GBS``), plus the tile config the plan chose.
+achieved GB/s and the fraction of the device's peak bandwidth
+(``launch.roofline.peak_hbm_bandwidth``, keyed by ``device_kind``; on a
+device without a known peak the fraction is "not_measured"), plus the tile
+config the plan chose.
 
 The jnp-vs-dispatch wall-clock ratio for the fused kernel is always
 recorded; it is ASSERTED > 1.0 only on a compiled Pallas backend (TPU/GPU).
@@ -14,11 +15,12 @@ contract: bit-parity of the Pallas path against the jnp oracle for all four
 kernels, and the 1-vs-3 fused pass-count claim.
 
 Section B — legacy: per-cell roofline terms from the dry-run JSON cache
-(the table EXPERIMENTS.md §Roofline renders).
+(the table EXPERIMENTS.md §Roofline renders); no rows without the cache.
 """
 import glob
 import json
 import os
+import sys
 import time
 
 import numpy as np
@@ -77,7 +79,8 @@ def _kernel_legs(x, keys, pivot, pivots_gq, cap, bk):
 
 def _kernel_section(csv_rows, smoke):
     n_full = 2 ** 16 if smoke else 2 ** 20
-    platform = jax.default_backend()
+    kind = jax.devices()[0].device_kind
+    has_peak = kind in roofline.HBM_BW_BY_DEVICE_KIND
     rng = np.random.default_rng(0)
 
     default_bk = dispatch.resolve(None)
@@ -101,12 +104,17 @@ def _kernel_section(csv_rows, smoke):
             us = timed(op_call)
             streams = 2 if name == "segmented_select" else 1
             bytes_moved = streams * n_eff * 4 * passes
-            rl = roofline.kernel_roofline(bytes_moved, us * 1e-6, platform)
+            if has_peak:
+                rl = roofline.kernel_roofline(bytes_moved, us * 1e-6, kind)
+                peak = (f"peak={rl['peak_gbs']:.0f}GB/s "
+                        f"frac={rl['frac_of_peak']:.4f}")
+            else:
+                peak = "peak=not_measured frac=not_measured"
             csv_rows.append((
                 f"roofline/{name}/{bk.name}", f"{us:.0f}",
-                f"passes={passes} achieved={rl['achieved_gbs']:.2f}GB/s "
-                f"peak={rl['peak_gbs']:.0f}GB/s "
-                f"frac={rl['frac_of_peak']:.4f} n={n_eff} "
+                f"passes={passes} "
+                f"achieved={bytes_moved / (us * 1e-6) / 1e9:.2f}GB/s "
+                f"{peak} n={n_eff} device={kind} "
                 f"plan={p.backend.name} lanes={p.lanes} "
                 f"block_rows={p.block_rows}"))
 
@@ -162,8 +170,8 @@ def _kernel_section(csv_rows, smoke):
 def _dryrun_section(csv_rows):
     cells = sorted(glob.glob(os.path.join(DRYRUN_DIR, "*__pod1.json")))
     if not cells:
-        csv_rows.append(("roofline/NO_DRYRUN_CACHE", "0",
-                         "run python -m repro.launch.dryrun first"))
+        print("roofline: no dry-run cache (python -m repro.launch.dryrun "
+              "writes it); section B skipped", file=sys.stderr)
         return
     for path in cells:
         r = json.load(open(path))

@@ -1,6 +1,9 @@
 """Table V: communication & synchronization — structural round counts,
 collective phases and network volume per algorithm, measured from compiled
-HLO of the shard_map implementations (subprocess with 8 host devices)."""
+HLO of the shard_map implementations (subprocess with 8 host devices).
+
+The child only compiles and counts collectives, so it runs on the CPU
+(``JAX_PLATFORMS=cpu``) and never competes for a chip its parent holds."""
 import json
 import os
 import subprocess
@@ -60,6 +63,7 @@ print("JSON:" + json.dumps(out))
 def run(csv_rows):
     env = dict(os.environ)
     env["PYTHONPATH"] = os.path.join(REPO, "src")
+    env["JAX_PLATFORMS"] = "cpu"
     env.pop("XLA_FLAGS", None)
     res = subprocess.run([sys.executable, "-c", textwrap.dedent(_SUB)],
                          capture_output=True, text=True, env=env, timeout=900)

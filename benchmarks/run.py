@@ -69,6 +69,8 @@ def main() -> None:
             sys.exit(f"unknown bench module(s): {sorted(unknown)} "
                      f"(have {sorted(pool)})")
         selected = [(n, pool[n]) for n in sorted(wanted)]
+    from repro.launch.chip import enable_compile_cache
+    enable_compile_cache()
     rows = [("name", "us_per_call", "derived")]
     failed = False
     for name, mod in selected:
@@ -99,10 +101,12 @@ def main() -> None:
 
     import jax
     from repro.kernels import dispatch
+    from repro.launch.chip import device_record
     payload = {name: {"us_per_call": _num(us), "derived": derived}
                for name, us, derived in rows[1:]}
     payload["_meta"] = {
         "platform": jax.default_backend(),
+        "device": device_record(),
         "default_backend": dispatch.select_backend().name,
         "jax": jax.__version__,
         "smoke": smoke,

@@ -13,6 +13,9 @@ from repro.configs import get_config
 from repro.launch.serve import (calibrate_int8_scale, calibrate_int8_scales,
                                 generate)
 from repro.models import model
+from repro.launch.chip import enable_compile_cache
+
+enable_compile_cache()
 
 cfg = get_config("h2o-danube-1.8b").reduced()
 params = model.init_params(cfg, jax.random.PRNGKey(0))

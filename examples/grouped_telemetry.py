@@ -17,6 +17,9 @@ import jax.numpy as jnp
 from repro.core import gk_select_grouped, local_ops
 from repro.kernels import ops as kernel_ops
 from repro.launch import QuantileService
+from repro.launch.chip import enable_compile_cache
+
+enable_compile_cache()
 
 rng = np.random.default_rng(0)
 

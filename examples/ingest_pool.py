@@ -18,6 +18,9 @@ import time
 import numpy as np
 
 from repro.launch import IngestPool, QuantileService, default_ingest_workers
+from repro.launch.chip import enable_compile_cache
+
+enable_compile_cache()
 
 rng = np.random.default_rng(0)
 svc = QuantileService(eps=0.05, budget=128)

@@ -7,6 +7,9 @@ import jax.numpy as jnp
 
 from repro.core import (exact_quantile, gk_select, gk_select_multi,
                         approx_quantile, full_sort_quantile, GKSketch)
+from repro.launch.chip import enable_compile_cache
+
+enable_compile_cache()
 
 rng = np.random.default_rng(0)
 

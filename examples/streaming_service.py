@@ -13,6 +13,9 @@ import jax.numpy as jnp
 
 from repro.core import reset_sketch_sorts, sketch_sorts
 from repro.launch import QuantileService
+from repro.launch.chip import enable_compile_cache
+
+enable_compile_cache()
 
 rng = np.random.default_rng(0)
 svc = QuantileService(eps=0.01)

@@ -8,6 +8,7 @@ import dataclasses
 
 from repro.configs import get_config
 from repro.launch.train import train_loop
+from repro.launch.chip import enable_compile_cache
 
 
 def main():
@@ -15,6 +16,7 @@ def main():
     ap.add_argument("--steps", type=int, default=200)
     ap.add_argument("--ckpt-dir", default="/tmp/repro_train_lm")
     args = ap.parse_args()
+    enable_compile_cache()
 
     # ~100M params: stablelm family, shrunk depth/width but real vocab
     base = get_config("stablelm-1.6b")

@@ -25,9 +25,10 @@ import functools
 from typing import Sequence
 
 import jax
+import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
-from . import local_ops
+from . import local_ops, lowering
 
 # Engine bodies + collective helpers re-exported for compatibility: every
 # pre-refactor import path (benchmarks, tests, downstream code) keeps
@@ -46,6 +47,36 @@ from .engine import (shard_map_compat, tree_reduce_candidates,
 # ---------------------------------------------------------------------------
 
 
+@functools.lru_cache(maxsize=128)
+def _quantile_program(mesh: Mesh, axis: str, method: str, q: float,
+                      eps: float, speculative: bool, reduce_strategy: str,
+                      fused: bool, backend):
+    """The jitted shard_map program of one ``distributed_quantile`` plan,
+    built once per (mesh, static parameters) and replayed after."""
+    num_shards = mesh.shape[axis]
+    fused_fn = None
+    if fused:
+        from ..kernels.ops import make_fused_fn   # lazy: kernels optional
+        fused_fn = make_fused_fn(backend=backend)
+    bodies = {
+        "gk_select": functools.partial(gk_select_sharded, q=q, eps=eps,
+                                       axis=axis, num_shards=num_shards,
+                                       speculative=speculative,
+                                       reduce_strategy=reduce_strategy,
+                                       fused_fn=fused_fn),
+        "approx": functools.partial(approx_quantile_sharded, q=q, eps=eps,
+                                    axis=axis, num_shards=num_shards),
+        "afs": functools.partial(count_discard_sharded, q=q, axis=axis,
+                                 num_shards=num_shards, collect_counts=False),
+        "jeffers": functools.partial(count_discard_sharded, q=q, axis=axis,
+                                     num_shards=num_shards, collect_counts=True),
+        "full_sort": functools.partial(full_sort_sharded, q=q, axis=axis,
+                                       num_shards=num_shards),
+    }
+    return jax.jit(shard_map_compat(bodies[method], mesh=mesh,
+                                    in_specs=(P(axis),), out_specs=P()))
+
+
 def distributed_quantile(x: jax.Array, q: float, mesh: Mesh, *,
                          axis: str = "data", eps: float = 0.01,
                          method: str = "gk_select", speculative: bool = False,
@@ -54,7 +85,9 @@ def distributed_quantile(x: jax.Array, q: float, mesh: Mesh, *,
                          check_nans: bool = True) -> jax.Array:
     """Exact (or approximate, method='approx') quantile of a 1-D array sharded
     over ``axis`` of ``mesh``.  The entry point used by optimizer/serving
-    integrations.
+    integrations.  The job runs as one jitted program per (mesh, static
+    parameters); ``x`` is best placed already sharded
+    (``NamedSharding(mesh, P(axis))``), else the program reshards it.
 
     Exactness guarantee: for every exact method ('gk_select', 'afs',
     'jeffers', 'full_sort') the answer is bit-identical to the global sort
@@ -74,36 +107,39 @@ def distributed_quantile(x: jax.Array, q: float, mesh: Mesh, *,
         raise ValueError("distributed_quantile expects a flat array")
     if x.size % num_shards:
         raise ValueError(f"size {x.size} % shards {num_shards} != 0 — pad first")
+    if fused and method != "gk_select":
+        raise ValueError(f"fused=True only applies to method='gk_select', "
+                         f"got method={method!r}")
     if check_nans:
         local_ops.reject_nans(x, "distributed_quantile")
+    program = _quantile_program(mesh, axis, method, float(q), eps,
+                                speculative, reduce_strategy, fused,
+                                backend if fused else None)
+    return lowering.call(program, x)
 
+
+@functools.lru_cache(maxsize=128)
+def _multi_program(mesh: Mesh, axis: str, qs: tuple, eps: float,
+                   reduce_strategy: str, fused: bool, backend,
+                   warm: bool, cap):
+    """The jitted shard_map program of one ``distributed_quantile_multi``
+    plan; a warm program takes the (Q,) pivots as a replicated argument."""
     fused_fn = None
     if fused:
-        if method != "gk_select":
-            raise ValueError(f"fused=True only applies to method='gk_select', "
-                             f"got method={method!r}")
-        from ..kernels.ops import make_fused_fn   # lazy: kernels optional
-        fused_fn = make_fused_fn(backend=backend)
-
-    bodies = {
-        "gk_select": functools.partial(gk_select_sharded, q=q, eps=eps,
-                                       axis=axis, num_shards=num_shards,
-                                       speculative=speculative,
-                                       reduce_strategy=reduce_strategy,
-                                       fused_fn=fused_fn),
-        "approx": functools.partial(approx_quantile_sharded, q=q, eps=eps,
-                                    axis=axis, num_shards=num_shards),
-        "afs": functools.partial(count_discard_sharded, q=q, axis=axis,
-                                 num_shards=num_shards, collect_counts=False),
-        "jeffers": functools.partial(count_discard_sharded, q=q, axis=axis,
-                                     num_shards=num_shards, collect_counts=True),
-        "full_sort": functools.partial(full_sort_sharded, q=q, axis=axis,
-                                       num_shards=num_shards),
-    }
-    body = bodies[method]
-    spec = P(axis)
-    fn = shard_map_compat(body, mesh=mesh, in_specs=(spec,), out_specs=P())
-    return fn(x)
+        from ..kernels.ops import make_fused_multi_fn   # lazy: kernels optional
+        fused_fn = make_fused_multi_fn(backend=backend)
+    body = functools.partial(gk_select_multi_sharded, qs=qs, eps=eps,
+                             axis=axis, num_shards=mesh.shape[axis],
+                             reduce_strategy=reduce_strategy,
+                             fused_fn=fused_fn, cap=cap)
+    if warm:
+        def run(x_local, pivots):
+            return body(x_local, pivots=pivots)
+        in_specs = (P(axis), P())
+    else:
+        run, in_specs = body, (P(axis),)
+    return jax.jit(shard_map_compat(run, mesh=mesh, in_specs=in_specs,
+                                    out_specs=P()))
 
 
 def distributed_quantile_multi(x: jax.Array, qs: Sequence[float], mesh: Mesh,
@@ -138,15 +174,10 @@ def distributed_quantile_multi(x: jax.Array, qs: Sequence[float], mesh: Mesh,
         raise ValueError(f"size {x.size} % shards {num_shards} != 0 — pad first")
     if check_nans:
         local_ops.reject_nans(x, "distributed_quantile_multi")
-
-    fused_fn = None
-    if fused:
-        from ..kernels.ops import make_fused_multi_fn   # lazy: kernels optional
-        fused_fn = make_fused_multi_fn(backend=backend)
-
-    body = functools.partial(gk_select_multi_sharded, qs=qs, eps=eps,
-                             axis=axis, num_shards=num_shards,
-                             reduce_strategy=reduce_strategy,
-                             fused_fn=fused_fn, pivots=pivots, cap=cap)
-    fn = shard_map_compat(body, mesh=mesh, in_specs=(P(axis),), out_specs=P())
-    return fn(x)
+    program = _multi_program(mesh, axis, qs, eps, reduce_strategy, fused,
+                             backend if fused else None, pivots is not None,
+                             None if cap is None else int(cap))
+    if pivots is None:
+        return lowering.call(program, x)
+    return lowering.call(program, x,
+                         jnp.asarray(pivots, x.dtype).reshape(len(qs)))

@@ -55,16 +55,10 @@ def _axis_size(axis) -> int:
 
 
 def shard_map_compat(body, *, mesh, in_specs, out_specs):
-    """``jax.shard_map`` across jax versions: new-style ``jax.shard_map``
-    (check_vma) when present, ``jax.experimental.shard_map`` (check_rep)
-    otherwise.  Replication checking is off either way — the bodies return
+    """``jax.shard_map`` with replication checking off — the bodies return
     deliberately replicated scalars from psum/pmax chains."""
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(body, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=False)
-    from jax.experimental.shard_map import shard_map
-    return shard_map(body, mesh=mesh, in_specs=in_specs,
-                     out_specs=out_specs, check_rep=False)
+    return jax.shard_map(body, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False)
 
 
 def tree_reduce_candidates(buf: jax.Array, axis: str, num_shards: int,

@@ -13,6 +13,8 @@ from typing import Tuple
 import jax
 import jax.numpy as jnp
 
+from . import lowering
+
 
 def _sentinels(dtype):
     """(lowest, highest) total-order sentinels for a dtype."""
@@ -54,7 +56,7 @@ def reject_nans(x: jax.Array, where: str) -> None:
     cannot raise) — callers embedding the engine in larger jitted programs
     own their NaN hygiene, and the contract is documented at each entry.
     """
-    if isinstance(x, jax.core.Tracer):
+    if isinstance(x, jax.core.Tracer) or lowering.active():
         return
     x = jnp.asarray(x)
     if not jnp.issubdtype(x.dtype, jnp.floating):

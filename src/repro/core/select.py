@@ -24,7 +24,7 @@ from typing import Sequence
 import jax
 import jax.numpy as jnp
 
-from . import local_ops
+from . import local_ops, lowering
 from .sketch import local_sample_sketch, query_merged_sketch, sample_sketch_params
 
 
@@ -138,8 +138,9 @@ def gk_select(parts: jax.Array, q: float, *, eps: float = 0.01,
     """
     if check_nans:
         local_ops.reject_nans(parts, "gk_select")
-    return _gk_select_jit(parts, q, eps=eps, speculative=speculative,
-                          block_select=block_select, k=k, backend=backend)
+    return lowering.call(_gk_select_jit, parts, q, eps=eps,
+                         speculative=speculative, block_select=block_select,
+                         k=k, backend=backend)
 
 
 def exact_quantile(x: jax.Array, q: float, *, eps: float = 0.01,
@@ -209,13 +210,16 @@ def _gk_select_multi_jit(parts: jax.Array, qs: tuple, *, eps: float = 0.01,
 
         return jax.vmap(resolve_one)(pivots, ks, counts, below, above)
 
-    def one(pivot, k):
+    def one(pk):
+        pivot, k = pk
         counts, below, above = jax.vmap(
             lambda x: local_ops.fused_count_extract(x, pivot, cap))(parts)
         counts = counts.sum(0)
         return local_ops.resolve(pivot, k, counts[0], counts[1], below, above, cap)
 
-    return jax.vmap(one)(pivots, ks)
+    # one level at a time: a vmap over the Q pivots would hold Q masked
+    # copies of the whole input at once (Q x 4 GB at the paper's 10^9 f32)
+    return jax.lax.map(one, (pivots, ks))
 
 
 def gk_select_multi(parts: jax.Array, qs: tuple, *, eps: float = 0.01,
@@ -230,6 +234,6 @@ def gk_select_multi(parts: jax.Array, qs: tuple, *, eps: float = 0.01,
     ``gk_select``)."""
     if check_nans:
         local_ops.reject_nans(parts, "gk_select_multi")
-    return _gk_select_multi_jit(parts, tuple(qs), eps=eps,
-                                speculative=speculative,
-                                block_select=block_select, backend=backend)
+    return lowering.call(_gk_select_multi_jit, parts, tuple(qs), eps=eps,
+                         speculative=speculative, block_select=block_select,
+                         backend=backend)

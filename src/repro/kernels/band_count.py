@@ -18,7 +18,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from .partition_count import (DEFAULT_BLOCK_ROWS, check_lanes,
-                              tpu_call_params)
+                              COMPILER_PARAMS, work_dtype)
 
 
 def _band_count_kernel(bounds_ref, x_ref, out_ref, *, n_valid: int,
@@ -29,7 +29,7 @@ def _band_count_kernel(bounds_ref, x_ref, out_ref, *, n_valid: int,
     def _init():
         out_ref[0] = jnp.int32(0)
 
-    x = x_ref[...]
+    x = x_ref[...].astype(bounds_ref.dtype)
     lo = bounds_ref[0]
     hi = bounds_ref[1]
     lanes = x.shape[1]
@@ -42,18 +42,20 @@ def _band_count_kernel(bounds_ref, x_ref, out_ref, *, n_valid: int,
 
 
 @functools.partial(jax.jit, static_argnames=("n_valid", "block_rows",
-                                             "interpret", "vmem_limit"))
+                                             "interpret"))
 def band_count(x2d: jax.Array, lo: jax.Array, hi: jax.Array, *, n_valid: int,
-               block_rows: int = DEFAULT_BLOCK_ROWS,
-               interpret: bool = True, vmem_limit: int = None) -> jax.Array:
-    """int32 count of elements of the first n_valid lanes inside (lo, hi)."""
+               interpret: bool,
+               block_rows: int = DEFAULT_BLOCK_ROWS) -> jax.Array:
+    """int32 count of elements of the first n_valid lanes inside (lo, hi).
+    2-byte floats are compared in f32 (``work_dtype``)."""
     rows, lanes = x2d.shape
     check_lanes(lanes)
     block_rows = min(block_rows, rows)
     grid = (pl.cdiv(rows, block_rows),)
     kernel = functools.partial(_band_count_kernel, n_valid=n_valid,
                                block_rows=block_rows)
-    bounds = jnp.stack([lo, hi]).astype(x2d.dtype)
+    bounds = jnp.stack([lo, hi]).astype(x2d.dtype).astype(
+        work_dtype(x2d.dtype))
     out = pl.pallas_call(
         kernel,
         grid=grid,
@@ -64,6 +66,6 @@ def band_count(x2d: jax.Array, lo: jax.Array, hi: jax.Array, *, n_valid: int,
         out_specs=pl.BlockSpec(memory_space=pltpu.SMEM),
         out_shape=jax.ShapeDtypeStruct((1,), jnp.int32),
         interpret=interpret,
-        **tpu_call_params(interpret, vmem_limit),
+        compiler_params=COMPILER_PARAMS,
     )(bounds, x2d)
     return out[0]

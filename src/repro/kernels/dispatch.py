@@ -21,9 +21,11 @@ This module is the registry that picks between them *per platform at trace
 time* and sizes the Pallas grid/BlockSpec tiling from dtype + array size:
 
   ``select_backend()``      platform -> Backend (env-overridable)
-  ``plan(...)``             (backend, kernel, dtype, n, residents) ->
+  ``plan(...)``             (backend, kernel, dtype, n, footprint) ->
                             LaunchPlan: lanes, block_rows, VMEM-budget
-                            check with clean fallback to jnp
+                            check (a compiled backend refuses a kernel
+                            that does not fit; the interpreter warns and
+                            falls back to jnp)
   ``run_<kernel>(...)``     execute under a plan, returning
                             ``(outputs, plan)`` so callers can account
                             passes and record tile configs
@@ -45,6 +47,7 @@ at trace time — flip them before the first call, not between jit replays.
 """
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import functools
 import os
@@ -57,6 +60,7 @@ from . import ref
 from .partition_count import partition_count
 from .band_count import band_count as _band_count_kernel
 from .fused_select import (fused_select, fused_select_multi,
+                           select_vmem_bytes,
                            byte_histogram as _byte_histogram_kernel)
 from .segmented_select import segmented_select
 
@@ -103,6 +107,23 @@ _GPU_PLATFORMS = ("gpu", "cuda", "rocm")
 
 # kernels whose pallas_gpu launch failed once: gated to jnp from then on
 _GPU_BROKEN: dict = {}
+
+# the open ``record_plans`` list, if any
+_RECORD = None
+
+
+@contextlib.contextmanager
+def record_plans():
+    """Collect ``(kernel, LaunchPlan)`` of every run_* call made inside
+    the block, from any thread; a call traced inside a jit records once,
+    at trace time.  ``chip_smoke.py`` checks with it that a kernel phase
+    really ran a Pallas plan.  Outside the block nothing is kept."""
+    global _RECORD
+    outer, _RECORD = _RECORD, []
+    try:
+        yield _RECORD
+    finally:
+        _RECORD = outer
 
 
 def _platform(platform: str | None) -> str:
@@ -169,7 +190,6 @@ def resolve(backend=None, platform: str | None = None) -> Backend:
 # ---------------------------------------------------------------------------
 
 LANE_MULTIPLE = 128     # VREG lane width every trailing dim must respect
-MIN_BLOCK_ROWS = 8      # one f32 sublane tile
 
 
 def lanes_for(dtype) -> int:
@@ -188,11 +208,21 @@ def lanes_for(dtype) -> int:
     return 1024
 
 
-def pad_to_lanes(x: jax.Array, lanes: int) -> jax.Array:
-    """Flat -> (rows, lanes) row-major, zero-padded at the tail (pad values
-    are masked by ``n_valid`` inside the kernels)."""
+def sublanes_for(dtype) -> int:
+    """Rows of one native (sublanes, 128) tile: 8 for 32-bit dtypes, 16
+    for 2-byte, 32 for 1-byte.  Streamed row counts and block rows are
+    multiples of it."""
+    return 8 * max(1, 4 // jnp.dtype(dtype).itemsize)
+
+
+def pad_to_lanes(x: jax.Array, lanes: int, sublanes: int = None) -> jax.Array:
+    """Flat -> (rows, lanes) row-major, rows rounded up to ``sublanes``
+    (default: the dtype's sublane tile), zero-padded at the tail (pad
+    values are masked by ``n_valid`` inside the kernels)."""
     n = x.size
+    sub = sublanes or sublanes_for(x.dtype)
     rows = max(1, -(-n // lanes))
+    rows = -(-rows // sub) * sub
     pad = rows * lanes - n
     if pad:
         x = jnp.concatenate([x.ravel(), jnp.zeros((pad,), x.dtype)])
@@ -204,10 +234,12 @@ class LaunchPlan:
     """The resolved execution recipe for one kernel call.
 
     ``backend`` is the backend that will actually run (it may differ from
-    the requested one when the VMEM-budget check or the GPU gate fell back
-    to jnp — ``reason`` says why).  ``lanes``/``block_rows`` are 0 on jnp
-    plans (no tiling).  ``vmem_bytes`` is the budgeted footprint the plan
-    assumed: residents + a double-buffered pair of streamed tiles.
+    the requested one when the interpreter's VMEM-budget check or the GPU
+    gate fell back to jnp — ``reason`` says why, and a warning is raised).
+    ``lanes``/``block_rows`` are 0 on jnp plans (no tiling).
+    ``vmem_bytes`` is the footprint the plan budgeted: residents plus
+    double-buffered streamed tiles (plus the select kernels' work
+    buffers).  Kernels run under the compiler's default VMEM limit.
     """
     backend: Backend
     lanes: int = 0
@@ -216,17 +248,17 @@ class LaunchPlan:
     reason: str = ""
 
 
-def plan(backend, kernel: str, dtype, n: int, *, streams: int = 1,
-         resident_lanes: int = 0) -> LaunchPlan:
-    """Size the grid for one kernel call, or fall back to jnp cleanly.
+def plan(backend, kernel: str, dtype, n: int, *,
+         footprint=None) -> LaunchPlan:
+    """Size the grid for one kernel call.
 
-    ``streams`` is how many equally-shaped arrays the kernel reads per grid
-    step (2 for segmented_select's values+keys).  ``resident_lanes`` is the
-    number of dtype-sized lanes held in VMEM across ALL grid steps (the
-    running candidate buffers: 2*cap_pad per output row).  If even the
-    minimum tile cannot fit next to the residents inside the backend's
-    VMEM budget, the plan degrades to the jnp backend instead of letting
-    the compiler (or interpreter) blow up — ``reason`` records the verdict.
+    ``footprint(block_rows, lanes)`` is the kernel's VMEM need in bytes;
+    the default is one double-buffered streamed tile (the counting
+    kernels).  If even the minimum tile does not fit the backend's VMEM
+    budget, a compiled backend raises ``ValueError`` naming the footprint
+    and the budget (the caller asked for the kernel; running jnp instead
+    would hide that it did not run), and the interpreter, which has no
+    VMEM, warns and falls back to jnp — ``reason`` records the verdict.
     """
     backend = resolve(backend)
     if backend.kind == "jnp":
@@ -236,30 +268,45 @@ def plan(backend, kernel: str, dtype, n: int, *, streams: int = 1,
 
     itemsize = jnp.dtype(dtype).itemsize
     lanes = lanes_for(dtype)
-    rows = max(1, -(-int(n) // lanes))
+    min_rows = sublanes_for(dtype)
+    rows = -(-max(1, -(-int(n) // lanes)) // min_rows) * min_rows
     row_bytes = lanes * itemsize
-    resident_bytes = resident_lanes * itemsize
 
-    def footprint(block_rows: int) -> int:
-        # double-buffered streamed tiles + persistent residents; the fused
-        # kernels' top_k merge operand (~one tile row + the buffer row) is
-        # covered by the 2x tile term
-        return resident_bytes + 2 * streams * block_rows * row_bytes
+    if footprint is None:
+        def footprint(block_rows: int, lanes_: int) -> int:
+            return 2 * block_rows * row_bytes
 
-    if footprint(MIN_BLOCK_ROWS) > backend.vmem_budget:
-        return LaunchPlan(JNP, reason=(
-            f"{kernel}: residents {resident_bytes}B + min tile exceed "
-            f"{backend.name} VMEM budget {backend.vmem_budget}B — "
-            f"fell back to jnp"))
+    need = footprint(min_rows, lanes)
+    if need > backend.vmem_budget:
+        verdict = (f"{kernel}: the minimum tile needs {need}B and would "
+                   f"exceed {backend.name} VMEM budget "
+                   f"{backend.vmem_budget}B")
+        if backend.compiled:
+            raise ValueError(
+                f"{verdict}; lower the candidate cap (a smaller eps) or "
+                f"pass backend='jnp'")
+        reason = f"{verdict} — fell back to jnp"
+        warnings.warn(reason, RuntimeWarning, stacklevel=2)
+        return LaunchPlan(JNP, reason=reason)
 
-    target_rows = max(MIN_BLOCK_ROWS, backend.tile_bytes // row_bytes)
+    target_rows = max(min_rows, backend.tile_bytes // row_bytes)
     block_rows = 1 << (int(target_rows).bit_length() - 1)   # pow2 floor
-    while block_rows > MIN_BLOCK_ROWS and \
-            footprint(block_rows) > backend.vmem_budget:
+    while block_rows > min_rows and \
+            footprint(block_rows, lanes) > backend.vmem_budget:
         block_rows //= 2
-    block_rows = max(1, min(block_rows, rows))
+    block_rows = min(block_rows, rows)
     return LaunchPlan(backend, lanes=lanes, block_rows=block_rows,
-                      vmem_bytes=footprint(block_rows))
+                      vmem_bytes=footprint(block_rows, lanes))
+
+
+def _select_footprint(dtype, num_rows: int, cap_pad: int, streams: int = 1):
+    """``plan`` footprint of the band-select kernels (fused_select*,
+    segmented_select): their running buffers and work buffer scale with
+    the pivot rows, ``cap_pad`` and the tile."""
+    def footprint(block_rows: int, lanes: int) -> int:
+        return select_vmem_bytes(dtype, num_rows, cap_pad, block_rows,
+                                 lanes, streams)
+    return footprint
 
 
 def cap_pad_for(cap: int) -> int:
@@ -277,6 +324,8 @@ def _gate(plan_: LaunchPlan, kernel: str, pallas_thunk, jnp_thunk):
     calls return a jnp plan directly), and the jnp oracle answers instead.
     TPU/interpret failures are real bugs and propagate.
     """
+    if _RECORD is not None:
+        _RECORD.append((kernel, plan_))
     if plan_.backend.kind != "pallas":
         return jnp_thunk()
     try:
@@ -343,8 +392,7 @@ def run_partition_count(x: jax.Array, pivot, *, backend=None):
         x2d = pad_to_lanes(x, p.lanes)
         return partition_count(x2d, pivot, n_valid=x.size,
                                block_rows=p.block_rows,
-                               interpret=p.backend.interpret,
-                               vmem_limit=p.vmem_bytes or None)
+                               interpret=p.backend.interpret)
 
     return _gate(p, "partition_count", _pallas,
                  lambda: _jnp_partition_count(x, pivot)), p
@@ -361,8 +409,7 @@ def run_band_count(x: jax.Array, lo, hi, *, backend=None):
         x2d = pad_to_lanes(x, p.lanes)
         return _band_count_kernel(x2d, lo, hi, n_valid=x.size,
                                   block_rows=p.block_rows,
-                                  interpret=p.backend.interpret,
-                                  vmem_limit=p.vmem_bytes or None)
+                                  interpret=p.backend.interpret)
 
     return _gate(p, "band_count", _pallas,
                  lambda: _jnp_band_count(x, lo, hi)), p
@@ -374,15 +421,14 @@ def run_fused_select(x: jax.Array, pivot, cap: int, *, backend=None):
     x = x.ravel()
     cap_pad = cap_pad_for(cap)
     p = plan(backend, "fused_select", x.dtype, x.size,
-             resident_lanes=2 * cap_pad)
+             footprint=_select_footprint(x.dtype, 1, cap_pad))
     pivot = jnp.asarray(pivot, x.dtype)
 
     def _pallas():
         x2d = pad_to_lanes(x, p.lanes)
         counts, below, above = fused_select(
             x2d, pivot, n_valid=x.size, cap_pad=cap_pad,
-            block_rows=p.block_rows, interpret=p.backend.interpret,
-            vmem_limit=p.vmem_bytes or None)
+            block_rows=p.block_rows, interpret=p.backend.interpret)
         return counts, below[:cap], above[:cap]
 
     return _gate(p, "fused_select", _pallas,
@@ -397,15 +443,14 @@ def run_fused_select_multi(x: jax.Array, pivots: jax.Array, cap: int, *,
     Q = int(pivots.shape[0])
     cap_pad = cap_pad_for(cap)
     p = plan(backend, "fused_select_multi", x.dtype, x.size,
-             resident_lanes=2 * Q * cap_pad)
+             footprint=_select_footprint(x.dtype, Q, cap_pad))
     pivots = jnp.asarray(pivots, x.dtype)
 
     def _pallas():
         x2d = pad_to_lanes(x, p.lanes)
         counts, below, above = fused_select_multi(
             x2d, pivots, n_valid=x.size, cap_pad=cap_pad,
-            block_rows=p.block_rows, interpret=p.backend.interpret,
-            vmem_limit=p.vmem_bytes or None)
+            block_rows=p.block_rows, interpret=p.backend.interpret)
         return counts, below[:, :cap], above[:, :cap]
 
     return _gate(p, "fused_select_multi", _pallas,
@@ -420,17 +465,18 @@ def run_segmented_select(values: jax.Array, keys: jax.Array,
     G, Q = (int(d) for d in pivots.shape)
     cap_pad = cap_pad_for(cap)
     p = plan(backend, "segmented_select", values.dtype, values.size,
-             streams=2, resident_lanes=2 * G * Q * cap_pad)
+             footprint=_select_footprint(values.dtype, G * Q, cap_pad,
+                                         streams=2))
     pivots = jnp.asarray(pivots, values.dtype)
     keys = keys.ravel().astype(jnp.int32)
 
     def _pallas():
         x2d = pad_to_lanes(values, p.lanes)
-        k2d = pad_to_lanes(keys, p.lanes)
+        k2d = pad_to_lanes(keys, p.lanes, sublanes_for(values.dtype))
         counts, below, above = segmented_select(
             x2d, k2d, pivots, n_valid=values.size, cap_pad=cap_pad,
             num_groups=G, block_rows=p.block_rows,
-            interpret=p.backend.interpret, vmem_limit=p.vmem_bytes or None)
+            interpret=p.backend.interpret)
         return counts, below[:, :, :cap], above[:, :, :cap]
 
     return _gate(p, "segmented_select", _pallas,
@@ -446,10 +492,7 @@ def run_byte_histogram(u: jax.Array, prefix, mask, shift: int, *,
     if u.dtype != jnp.uint32:
         raise TypeError(f"byte_histogram wants sortable uint32, got "
                         f"{u.dtype}")
-    # the one-hot expansion inside the kernel keeps an extra
-    # (chunk_rows, 256) i32 live; fold it into the resident estimate
-    p = plan(backend, "byte_histogram", u.dtype, u.size,
-             resident_lanes=8 * 256 * 2)
+    p = plan(backend, "byte_histogram", u.dtype, u.size)
     prefix = jnp.asarray(prefix, jnp.uint32)
     mask = jnp.asarray(mask, jnp.uint32)
 
@@ -457,8 +500,7 @@ def run_byte_histogram(u: jax.Array, prefix, mask, shift: int, *,
         u2d = pad_to_lanes(u, p.lanes)
         return _byte_histogram_kernel(u2d, prefix, mask, n_valid=u.size,
                                       shift=shift, block_rows=p.block_rows,
-                                      interpret=p.backend.interpret,
-                                      vmem_limit=p.vmem_bytes or None)
+                                      interpret=p.backend.interpret)
 
     return _gate(p, "byte_histogram", _pallas,
                  lambda: _jnp_byte_histogram(u, prefix, mask, shift)), p

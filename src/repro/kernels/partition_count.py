@@ -33,25 +33,20 @@ def check_lanes(lanes: int) -> None:
                          f"{LANE_MULTIPLE}, got {lanes}")
 
 
-def tpu_call_params(interpret: bool, vmem_limit) -> dict:
-    """compiler_params kwargs for a native (non-interpret) pallas_call:
-    sequential grid semantics + an explicit VMEM cap from the dispatch
-    plan.  Guarded for jax API drift (TPUCompilerParams in 0.4.x,
-    CompilerParams later); interpret mode takes none."""
-    if interpret:
-        return {}
-    cls = getattr(pltpu, "TPUCompilerParams", None)
-    if cls is None:
-        cls = getattr(pltpu, "CompilerParams", None)
-    if cls is None:
-        return {}
-    kwargs = {"dimension_semantics": ("arbitrary",)}
-    if vmem_limit:
-        kwargs["vmem_limit_bytes"] = int(vmem_limit)
-    try:
-        return {"compiler_params": cls(**kwargs)}
-    except TypeError:       # field set drifted; run with compiler defaults
-        return {}
+def work_dtype(dtype):
+    """The dtype a kernel compares in.  v5e's VPU has no 16-bit compare,
+    so 2-byte floats are upcast (exactly) to f32 inside the kernel; every
+    other dtype compares as itself."""
+    dtype = jnp.dtype(dtype)
+    if jnp.issubdtype(dtype, jnp.floating) and dtype.itemsize < 4:
+        return jnp.dtype(jnp.float32)
+    return dtype
+
+
+# Sequential grid: the accumulators are carried across steps.  VMEM runs
+# under the compiler's default limit; ``dispatch.plan`` sizes tiles to fit
+# its budget.  Interpret mode ignores the parameters.
+COMPILER_PARAMS = pltpu.CompilerParams(dimension_semantics=("arbitrary",))
 
 
 def _count3_kernel(pivot_ref, x_ref, out_ref, *, n_valid: int,
@@ -66,7 +61,7 @@ def _count3_kernel(pivot_ref, x_ref, out_ref, *, n_valid: int,
         out_ref[1] = jnp.int32(0)
         out_ref[2] = jnp.int32(0)
 
-    x = x_ref[...]
+    x = x_ref[...].astype(pivot_ref.dtype)
     pivot = pivot_ref[0]
     lanes = x.shape[1]
     base = step * block_rows * lanes
@@ -82,19 +77,16 @@ def _count3_kernel(pivot_ref, x_ref, out_ref, *, n_valid: int,
 
 
 @functools.partial(jax.jit, static_argnames=("n_valid", "block_rows",
-                                             "interpret", "vmem_limit"))
+                                             "interpret"))
 def partition_count(x2d: jax.Array, pivot: jax.Array, *, n_valid: int,
-                    block_rows: int = DEFAULT_BLOCK_ROWS,
-                    interpret: bool = True,
-                    vmem_limit: int = None) -> jax.Array:
+                    interpret: bool,
+                    block_rows: int = DEFAULT_BLOCK_ROWS) -> jax.Array:
     """(lt, eq, gt) int32 counts of the first ``n_valid`` elements of the
     row-major (rows, lanes) array vs the scalar pivot.
 
     VMEM footprint per step: block_rows * lanes * itemsize
-    (128 x 1024 x 4B = 512 KiB f32 — well under the ~16 MiB v5e VMEM,
-    leaving room for double-buffered prefetch of the next tile; the
-    dispatch plan shrinks block_rows when residents crowd the budget and
-    passes the assumed footprint as ``vmem_limit``).
+    (128 x 1024 x 4B = 512 KiB f32), double-buffered.  2-byte floats are
+    compared in f32 (``work_dtype``).
     """
     rows, lanes = x2d.shape
     check_lanes(lanes)
@@ -112,5 +104,5 @@ def partition_count(x2d: jax.Array, pivot: jax.Array, *, n_valid: int,
         out_specs=pl.BlockSpec(memory_space=pltpu.SMEM),
         out_shape=jax.ShapeDtypeStruct((3,), jnp.int32),
         interpret=interpret,
-        **tpu_call_params(interpret, vmem_limit),
-    )(pivot.reshape(1), x2d)
+        compiler_params=COMPILER_PARAMS,
+    )(pivot.astype(work_dtype(x2d.dtype)).reshape(1), x2d)

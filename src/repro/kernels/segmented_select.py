@@ -9,16 +9,15 @@ of the group-by workload, so this kernel collapses them into ONE sweep:
 values and keys tiles are loaded into VMEM once per grid step; every
 (group, level) pair re-scores the resident tile against its own membership
 mask and pivot, scatter-accumulating into its row of the revisited output
-blocks — (G*Q, 3) counts in SMEM and two (G*Q, cap_pad) running candidate
-selections in VMEM (the same merge-and-reselect strategy as
-``fused_select``).  Extra groups cost VPU compare/select work, never HBM
+blocks — (G*Q, 3) counts in SMEM and two (G*Q, C) running candidate
+selections in VMEM (the same bitonic fold as ``fused_select``, whose
+launcher this kernel shares).  Extra groups cost VPU compare/select work, never HBM
 reads.
 
-VMEM budget: tile + 2 * (G*Q, cap_pad) candidate blocks + merge operands —
-G*Q = 128 rows of 128 f32 lanes is 128 KiB of residents, comfortable in
-16 MiB VMEM; the unrolled per-group loop targets the O(10-100) group counts
-of telemetry/per-channel workloads (beyond that a bin-scatter layout wins;
-see DESIGN.md §7).
+VMEM budget (``fused_select.select_vmem_bytes``): tiles + 2 * (G*Q, C)
+candidate blocks + the work buffer.  The (group, level) loop targets the
+O(10-100) group counts of telemetry/per-channel workloads (beyond that a
+bin-scatter layout wins; see DESIGN.md §7).
 
 Layout contract matches ``fused_select``: flat shards padded to
 (rows, lanes) row-major (lanes any positive multiple of 128), true length
@@ -31,103 +30,30 @@ import functools
 
 import jax
 import jax.numpy as jnp
-from jax.experimental import pallas as pl
-from jax.experimental.pallas import tpu as pltpu
 
-from .partition_count import (DEFAULT_BLOCK_ROWS, check_lanes,
-                              tpu_call_params)
-from .fused_select import _sentinels, _valid_mask, _merge_below, _merge_above
-
-
-def _segmented_kernel(pivots_ref, x_ref, keys_ref, count_ref, below_ref,
-                      above_ref, *, n_valid: int, block_rows: int,
-                      cap_pad: int, num_groups: int, num_levels: int):
-    """One grid step: the tile is resident once; every (group, level) pair
-    masks it to its group and merges into its own output row."""
-    step = pl.program_id(0)
-    lo, hi = _sentinels(x_ref.dtype)
-    rows = num_groups * num_levels
-
-    @pl.when(step == 0)
-    def _init():
-        for r in range(rows):
-            count_ref[r, 0] = jnp.int32(0)
-            count_ref[r, 1] = jnp.int32(0)
-            count_ref[r, 2] = jnp.int32(0)
-        below_ref[...] = jnp.full((rows, cap_pad), lo, below_ref.dtype)
-        above_ref[...] = jnp.full((rows, cap_pad), hi, above_ref.dtype)
-
-    x = x_ref[...]
-    keys = keys_ref[...]
-    valid = _valid_mask(x, step, block_rows, n_valid)
-
-    for g in range(num_groups):
-        in_g = valid & (keys == g)
-        for qi in range(num_levels):
-            r = g * num_levels + qi
-            pivot = pivots_ref[r]
-            is_lt = in_g & (x < pivot)
-            is_gt = in_g & (x > pivot)
-            count_ref[r, 0] += jnp.sum(jnp.where(is_lt, 1, 0),
-                                       dtype=jnp.int32)
-            count_ref[r, 1] += jnp.sum(jnp.where(in_g & (x == pivot), 1, 0),
-                                       dtype=jnp.int32)
-            count_ref[r, 2] += jnp.sum(jnp.where(is_gt, 1, 0),
-                                       dtype=jnp.int32)
-            below_ref[r:r + 1, :] = _merge_below(
-                below_ref[r:r + 1, :], jnp.where(is_lt, x, lo), cap_pad)
-            above_ref[r:r + 1, :] = _merge_above(
-                above_ref[r:r + 1, :], jnp.where(is_gt, x, hi), cap_pad)
+from .partition_count import DEFAULT_BLOCK_ROWS
+from .fused_select import band_select
 
 
 @functools.partial(jax.jit, static_argnames=("n_valid", "cap_pad",
                                              "block_rows", "num_groups",
-                                             "interpret", "vmem_limit"))
+                                             "interpret"))
 def segmented_select(x2d: jax.Array, keys2d: jax.Array, pivots: jax.Array, *,
                      n_valid: int, cap_pad: int, num_groups: int,
-                     block_rows: int = DEFAULT_BLOCK_ROWS,
-                     interpret: bool = True, vmem_limit: int = None):
+                     interpret: bool, block_rows: int = DEFAULT_BLOCK_ROWS):
     """One streaming pass over the (rows, lanes) shard for every group and
     level: ``pivots`` is (G, Q); returns ``(counts (G, Q, 3),
     below (G, Q, cap_pad), above (G, Q, cap_pad))`` with per-row semantics
     identical to ``fused_select`` restricted to ``keys == g``."""
-    rows, lanes = x2d.shape
-    check_lanes(lanes)
     if keys2d.shape != x2d.shape:
         raise ValueError(f"keys shape {keys2d.shape} != values {x2d.shape}")
     if keys2d.dtype != jnp.int32:
         raise TypeError(f"keys must be int32, got {keys2d.dtype}")
-    if cap_pad <= 0 or cap_pad % 128:
-        raise ValueError(f"cap_pad must be a positive multiple of 128, "
-                         f"got {cap_pad}")
     G, Q = pivots.shape
     if G != num_groups:
         raise ValueError(f"pivots leading dim {G} != num_groups {num_groups}")
-    block_rows = min(block_rows, rows)
-    grid = (pl.cdiv(rows, block_rows),)
-    kernel = functools.partial(_segmented_kernel, n_valid=n_valid,
-                               block_rows=block_rows, cap_pad=cap_pad,
-                               num_groups=G, num_levels=Q)
-    counts, below, above = pl.pallas_call(
-        kernel,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec(memory_space=pltpu.SMEM),
-            pl.BlockSpec((block_rows, lanes), lambda i: (i, 0)),
-            pl.BlockSpec((block_rows, lanes), lambda i: (i, 0)),
-        ],
-        out_specs=[
-            pl.BlockSpec(memory_space=pltpu.SMEM),
-            pl.BlockSpec((G * Q, cap_pad), lambda i: (0, 0)),
-            pl.BlockSpec((G * Q, cap_pad), lambda i: (0, 0)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((G * Q, 3), jnp.int32),
-            jax.ShapeDtypeStruct((G * Q, cap_pad), x2d.dtype),
-            jax.ShapeDtypeStruct((G * Q, cap_pad), x2d.dtype),
-        ],
-        interpret=interpret,
-        **tpu_call_params(interpret, vmem_limit),
-    )(pivots.reshape(-1), x2d, keys2d)
+    counts, below, above = band_select(
+        x2d, pivots.reshape(-1), keys2d, n_valid=n_valid, cap_pad=cap_pad,
+        num_levels=Q, interpret=interpret, block_rows=block_rows)
     return (counts.reshape(G, Q, 3), below.reshape(G, Q, cap_pad),
             above.reshape(G, Q, cap_pad))

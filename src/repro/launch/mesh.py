@@ -16,12 +16,9 @@ import jax
 
 
 def _mesh(shape, axes):
-    """jax.make_mesh across versions: axis_types only where it exists."""
-    if hasattr(jax.sharding, "AxisType"):
-        return jax.make_mesh(shape, axes,
-                             axis_types=(jax.sharding.AxisType.Auto,)
-                             * len(axes))
-    return jax.make_mesh(shape, axes)
+    """``jax.make_mesh`` with every axis in Auto sharding mode."""
+    return jax.make_mesh(shape, axes,
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):

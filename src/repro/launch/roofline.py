@@ -16,16 +16,15 @@ Hardware constants: TPU v5e — 197 TFLOP/s bf16, 819 GB/s HBM, ~50 GB/s/link
 ICI (assignment-specified).
 
 The module also carries the KERNEL roofline helpers used by
-``benchmarks/bench_roofline.py`` (docs/PERFORMANCE.md): a per-platform peak
-HBM bandwidth table (``peak_hbm_bandwidth``, env-overridable via
-``REPRO_PEAK_BW_GBS``) and ``kernel_roofline`` which turns a measured
-(bytes_moved, seconds) pair into achieved GB/s and fraction-of-peak.  This
-file stays import-light (no jax at module scope) so the dry-run tooling can
-run anywhere; the platform probe imports jax lazily.
+``benchmarks/bench_roofline.py`` (docs/PERFORMANCE.md): a peak HBM bandwidth
+table keyed by ``device_kind`` (``peak_hbm_bandwidth``; a device that is not
+in the table is an error, never a default) and ``kernel_roofline`` which
+turns a measured (bytes_moved, seconds) pair into achieved GB/s and
+fraction-of-peak.  This file stays import-light (no jax at module scope) so
+the dry-run tooling can run anywhere; the device probe imports jax lazily.
 """
 from __future__ import annotations
 
-import os
 import re
 from typing import Dict, Optional, Tuple
 
@@ -33,43 +32,37 @@ PEAK_FLOPS = 197e12
 HBM_BW = 819e9
 LINK_BW = 50e9
 
-# Peak memory bandwidth per jax platform, bytes/s.  tpu = v5e HBM (matches
-# HBM_BW above); gpu = a modern HBM part (~H100 SXM order of magnitude);
-# cpu = a placeholder DDR figure — CPU numbers are for *relative* kernel
-# comparison only, never for frac-of-peak claims (docs/PERFORMANCE.md).
-HBM_BW_BY_PLATFORM = {
-    "tpu": HBM_BW,
-    "gpu": 1.6e12,
-    "cuda": 1.6e12,
-    "rocm": 1.6e12,
-    "cpu": 4e10,
+# Peak HBM bandwidth per jax ``device_kind``, bytes/s.  Source: Google
+# Cloud documentation, "TPU v5e" (16 GB of HBM at 819 GB/s per chip).
+HBM_BW_BY_DEVICE_KIND = {
+    "TPU v5 lite": 819e9,
 }
 
 
-def peak_hbm_bandwidth(platform: Optional[str] = None) -> float:
-    """Peak memory bandwidth (bytes/s) for ``platform`` (None = the default
-    jax backend's platform).  The ``REPRO_PEAK_BW_GBS`` env var (GB/s, e.g.
-    ``REPRO_PEAK_BW_GBS=2039`` for an H100 SXM) overrides the table — the
-    re-tuning knob for hardware the table doesn't know."""
-    env = os.environ.get("REPRO_PEAK_BW_GBS")
-    if env:
-        return float(env) * 1e9
-    if platform is None:
+def peak_hbm_bandwidth(device_kind: Optional[str] = None) -> float:
+    """Peak HBM bandwidth (bytes/s) of ``device_kind`` (None = the first
+    jax device).  ``KeyError`` for a device the table does not know."""
+    if device_kind is None:
         import jax   # lazy: keep module importable without a device runtime
-        platform = jax.default_backend()
-    return HBM_BW_BY_PLATFORM.get(platform.lower(), HBM_BW_BY_PLATFORM["cpu"])
+        device_kind = jax.devices()[0].device_kind
+    try:
+        return HBM_BW_BY_DEVICE_KIND[device_kind]
+    except KeyError:
+        raise KeyError(f"no peak HBM bandwidth for device kind "
+                       f"{device_kind!r}; known: "
+                       f"{sorted(HBM_BW_BY_DEVICE_KIND)}") from None
 
 
 def kernel_roofline(bytes_moved: float, seconds: float,
-                    platform: Optional[str] = None) -> Dict:
+                    device_kind: Optional[str] = None) -> Dict:
     """Achieved-vs-peak HBM bandwidth for one measured kernel invocation.
 
     ``bytes_moved`` is the kernel's modelled HBM traffic (input bytes times
     the backend-honest pass count from ``kernels.ops.hbm_passes``, plus
     output bytes); ``seconds`` the measured wall-clock.  Returns achieved
-    GB/s, the platform peak, and the fraction of peak — the quantity
+    GB/s, the device's peak, and the fraction of peak — the quantity
     bench_roofline reports per (kernel, backend)."""
-    peak = peak_hbm_bandwidth(platform)
+    peak = peak_hbm_bandwidth(device_kind)
     achieved = bytes_moved / seconds if seconds > 0 else 0.0
     return {
         "bytes_moved": float(bytes_moved),

@@ -3,8 +3,8 @@
 One place defines the test surface every quantile engine must survive:
 
   DTYPES         float32, bfloat16, int32, float64 (float64 needs x64 —
-                 cells enable it via ``jax.experimental.enable_x64`` or a
-                 subprocess-global switch)
+                 cells enable it via ``x64_ctx`` (``jax.enable_x64(True)``
+                 scoped to the cell) or a subprocess-global switch)
   DISTRIBUTIONS  uniform            wide continuous range
                  zipf               heavy-duplicate small support (Zipf-ish
                                     mass: collisions everywhere, including
@@ -27,6 +27,7 @@ compared in its own dtype (ranked via the injective upcast to float32).
 A new engine gets the whole grid by adding one runner to
 ``test_oracle_grid.py`` — the cases, oracles and rank rules live here.
 """
+import contextlib
 import math
 import zlib
 
@@ -40,6 +41,14 @@ QS = (0.001, 0.5, 0.999)
 
 def needs_x64(dtype: str) -> bool:
     return dtype == "float64"
+
+
+def x64_ctx(dtype: str):
+    """Context for one cell: x64 enabled for float64 cells only."""
+    if needs_x64(dtype):
+        import jax
+        return jax.enable_x64(True)
+    return contextlib.nullcontext()
 
 
 def _np_dtype(dtype: str):

@@ -9,7 +9,7 @@ import jax
 import jax.numpy as jnp
 import pytest
 
-from _grid import needs_x64
+from _grid import x64_ctx
 
 from repro.checkpoint import (latest_step, restore_checkpoint,
                               restore_checkpoint_flat,
@@ -20,12 +20,6 @@ from repro.core import (sketch_init, sketch_stack, sketch_unstack,
 from repro.launch import QuantileService
 
 DTYPES = ("float32", "bfloat16", "int32", "float64")
-
-
-def _ctx(dtype):
-    from jax.experimental import enable_x64
-    import contextlib
-    return enable_x64() if needs_x64(dtype) else contextlib.nullcontext()
 
 
 def _case(dtype, n, seed=0):
@@ -47,7 +41,7 @@ def _leaves_equal(a, b):
 class TestSketchStateRoundTrip:
     @pytest.mark.parametrize("dtype", DTYPES)
     def test_single_state_bit_exact(self, dtype, tmp_path):
-        with _ctx(dtype):
+        with x64_ctx(dtype):
             st = sketch_update(sketch_init(64, jnp.dtype(dtype)),
                                jnp.asarray(_case(dtype, 500)))
             save_checkpoint(str(tmp_path), 1, st)
@@ -57,7 +51,7 @@ class TestSketchStateRoundTrip:
 
     @pytest.mark.parametrize("dtype", DTYPES)
     def test_stacked_states_bit_exact(self, dtype, tmp_path):
-        with _ctx(dtype):
+        with x64_ctx(dtype):
             states = [sketch_update(sketch_init(32, jnp.dtype(dtype)),
                                     jnp.asarray(_case(dtype, 200, seed=i)))
                       for i in range(3)]
@@ -76,7 +70,7 @@ class TestServiceSnapshotRoundTrip:
         streams, same counts, same warm exact() bits — across the dtype
         grid (bf16 leaves round-trip through the uint16 view; f64 needs
         x64 enabled on both sides)."""
-        with _ctx(dtype):
+        with x64_ctx(dtype):
             svc = QuantileService(eps=0.05, dtype=jnp.dtype(dtype))
             streams = {f"s{i}": [_case(dtype, 150 + 31 * i, seed=10 * i + t)
                                  for t in range(2)] for i in range(3)}

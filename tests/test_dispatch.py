@@ -76,7 +76,9 @@ class TestTiling:
     def test_pad_to_lanes_shapes(self):
         x = jnp.arange(1500, dtype=jnp.float32)
         x2d = dispatch.pad_to_lanes(x, 1024)
-        assert x2d.shape == (2, 1024)
+        assert x2d.shape == (8, 1024)        # rows: one f32 sublane tile
+        assert dispatch.pad_to_lanes(x.astype(jnp.bfloat16),
+                                     2048).shape == (16, 2048)
         np.testing.assert_array_equal(np.asarray(x2d.ravel()[:1500]),
                                       np.asarray(x))
 
@@ -100,7 +102,7 @@ class TestTiling:
     def test_plan_clamps_to_rows(self):
         p = dispatch.plan(PALLAS_INTERPRET, "partition_count",
                           jnp.float32, 100)
-        assert p.block_rows == 1
+        assert p.block_rows == 8             # one sublane tile of rows
 
 
 class TestVMEMRejection:
@@ -109,18 +111,19 @@ class TestVMEMRejection:
 
     def test_plan_falls_back_to_jnp_with_reason(self):
         p = dispatch.plan(self.TINY, "fused_select", jnp.float32, 1 << 16,
-                          resident_lanes=2 * 128)
+                          footprint=dispatch._select_footprint(
+                              jnp.float32, 1, 128))
         assert p.backend is JNP
         assert "VMEM budget" in p.reason and "fell back to jnp" in p.reason
 
     def test_huge_residents_reject_even_on_tpu_budget(self):
-        # 8 MiB of resident candidate buffers + tiles can't fit in 16 MiB
-        # alongside double-buffered 512 KiB tiles at every grid step? They
-        # can — so push residents past the whole budget to force the path.
-        p = dispatch.plan(PALLAS_TPU, "segmented_select", jnp.float32,
-                          1 << 20, streams=2,
-                          resident_lanes=5 * (1 << 20))
-        assert p.backend is JNP and "exceed" in p.reason
+        # 1280 (group, level) rows x two double-buffered 1024-lane f32
+        # buffers are 21 MB of residents: past the whole 16 MiB budget.
+        # A compiled backend refuses instead of running jnp in its place.
+        with pytest.raises(ValueError, match="exceed pallas_tpu VMEM"):
+            dispatch.plan(PALLAS_TPU, "segmented_select", jnp.float32,
+                          1 << 20, footprint=dispatch._select_footprint(
+                              jnp.float32, 1280, 1024, streams=2))
 
     def test_oversized_tile_runs_clean_end_to_end(self):
         rng = np.random.default_rng(0)

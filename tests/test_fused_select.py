@@ -84,10 +84,23 @@ class TestFusedSelectParity:
         for br in [8, 64, 256]:
             x2d = ops.pad_to_tiles(x)
             c, b, a = fused_select(x2d, pivot, n_valid=x.size, cap_pad=128,
-                                   block_rows=br)
+                                   block_rows=br, interpret=True)
             np.testing.assert_array_equal(np.asarray(c), np.asarray(want[0]))
             np.testing.assert_array_equal(np.asarray(b), np.asarray(want[1]))
             np.testing.assert_array_equal(np.asarray(a), np.asarray(want[2]))
+
+    def test_buffer_wider_than_tile(self):
+        """A running buffer longer than one tile: every tile is folded
+        into the buffer's tail only (the large-cap path)."""
+        from repro.kernels.fused_select import fused_select
+        rng = np.random.default_rng(14)
+        x = jnp.asarray(rng.normal(size=40_000).astype(np.float32))
+        pivot = x[5]
+        want = fused_select_ref(x, pivot, 16_384)
+        c, b, a = fused_select(ops.pad_to_tiles(x), pivot, n_valid=x.size,
+                               cap_pad=16_384, block_rows=8, interpret=True)
+        for got, w in zip((c, b, a), want):
+            np.testing.assert_array_equal(np.asarray(got), np.asarray(w))
 
     @settings(max_examples=20, deadline=None)
     @given(st.integers(2, 5000), st.integers(0, 2 ** 31 - 1))

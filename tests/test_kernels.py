@@ -44,7 +44,7 @@ class TestPartitionCount:
         for br in [8, 64, 256]:
             x2d = ops.pad_to_tiles(x)
             got = np.asarray(partition_count(x2d, x[17], n_valid=x.size,
-                                             block_rows=br))
+                                             block_rows=br, interpret=True))
             assert np.array_equal(got, want), br
 
     @settings(max_examples=20, deadline=None)

@@ -1,5 +1,6 @@
-"""Launch-layer units: HLO analyzer parsing/trip counts, roofline math,
-sharding divisibility rules, dry-run shape applicability, multi-bit radix."""
+"""Launch-layer units: HLO analyzer parsing/trip counts, roofline math and
+peak table, chip set-up (compile cache, device check), sharding
+divisibility rules, dry-run shape applicability, multi-bit radix."""
 import functools
 import math
 
@@ -63,6 +64,45 @@ class TestRoofline:
         assert train == 3 * serve
         # MoE: active < total params
         assert cfg.active_param_count() < cfg.param_count()
+
+
+    def test_peak_keyed_by_device_kind(self):
+        assert rf.peak_hbm_bandwidth("TPU v5 lite") == 819e9
+        kr = rf.kernel_roofline(819e9, 2.0, "TPU v5 lite")
+        assert kr["frac_of_peak"] == 0.5
+
+    def test_unknown_device_has_no_peak(self):
+        with pytest.raises(KeyError, match="no peak HBM bandwidth"):
+            rf.peak_hbm_bandwidth("cpu")
+
+
+class TestChipSetup:
+    def test_compile_cache_env_dir_is_kept(self, monkeypatch):
+        from repro.launch import chip
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", "/elsewhere")
+        assert chip.enable_compile_cache() == "/elsewhere"
+
+    def test_compile_cache_default_is_fixed_repo_path(self, monkeypatch):
+        from repro.launch import chip
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+        was = jax.config.jax_compilation_cache_dir
+        try:
+            assert chip.enable_compile_cache() == str(chip.CACHE_DIR)
+            assert jax.config.jax_compilation_cache_dir == str(chip.CACHE_DIR)
+            assert chip.CACHE_DIR.parent == chip.REPO
+        finally:
+            jax.config.update("jax_compilation_cache_dir", was)
+
+    def test_no_accelerator_is_refused(self):
+        from repro.launch import chip
+        platform = jax.devices()[0].platform
+        assert chip.require_accelerator(platform)
+        other = "tpu" if platform != "tpu" else "cpu"
+        with pytest.raises(RuntimeError, match=f"no {other} device"):
+            chip.require_accelerator(other)
+        rec = chip.device_record()
+        assert rec["platform"] == platform
+        assert rec["count"] == len(jax.devices())
 
 
 class TestShapeRules:

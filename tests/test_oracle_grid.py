@@ -10,7 +10,7 @@ In-process runners cover the single-process engines (``gk_select``,
 the shard count played by pseudo-partitions / ragged ingest chunks;
 subprocess runners cover the shard_map engines (``distributed_quantile``
 single/multi) on real 1/3/6-device meshes.  float64 cells run under x64
-(scoped ``jax.experimental.enable_x64`` in-process; a global switch in the
+(scoped ``jax.enable_x64(True)`` in-process; a global switch in the
 subprocesses).
 """
 import os
@@ -24,17 +24,12 @@ import jax.numpy as jnp
 import pytest
 
 from _grid import (DTYPES, DISTRIBUTIONS, SHARD_COUNTS, QS, make_case,
-                   needs_x64, oracle_kth, oracle_quantile, grouped_oracle,
-                   ragged_chunks, target_rank)
+                   needs_x64, x64_ctx, oracle_kth, oracle_quantile,
+                   grouped_oracle, ragged_chunks, target_rank)
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 N = 3072                       # divisible by every shard count in the grid
 
-
-def _ctx(dtype):
-    from jax.experimental import enable_x64
-    import contextlib
-    return enable_x64() if needs_x64(dtype) else contextlib.nullcontext()
 
 
 def _cells():
@@ -49,7 +44,7 @@ class TestLocalEngines:
     def test_gk_select_and_multi(self, dtype, dist, parts):
         from repro.core import gk_select, gk_select_multi
         x = make_case(dist, dtype, N)
-        with _ctx(dtype):
+        with x64_ctx(dtype):
             xp = jnp.asarray(x).reshape(parts, -1)
             for q in QS:
                 want = oracle_quantile(x, q)
@@ -66,7 +61,7 @@ class TestServiceWarmPath:
     def test_warm_exact_matches_oracle(self, dtype, dist, parts):
         from repro.launch import QuantileService
         x = make_case(dist, dtype, N, seed=1)
-        with _ctx(dtype):
+        with x64_ctx(dtype):
             svc = QuantileService(eps=0.02, dtype=jnp.dtype(dtype))
             for c in ragged_chunks(x, parts, seed=parts):
                 svc.ingest("grid", c)
@@ -89,7 +84,7 @@ class TestGroupedEngine:
         x = make_case(dist, dtype, N, seed=2)
         rng = np.random.default_rng(3)
         keys = rng.integers(0, self.G, size=N).astype(np.int32)
-        with _ctx(dtype):
+        with x64_ctx(dtype):
             got = np.asarray(jax.device_get(gk_select_grouped(
                 jnp.asarray(x).reshape(parts, -1),
                 jnp.asarray(keys).reshape(parts, -1), QS,

@@ -14,7 +14,7 @@ import jax
 import jax.numpy as jnp
 import pytest
 
-from _grid import (DTYPES, DISTRIBUTIONS, QS, make_case, needs_x64,
+from _grid import (DTYPES, DISTRIBUTIONS, QS, make_case, x64_ctx,
                    oracle_kth, ragged_chunks, target_rank)
 
 from repro.core import reset_sketch_sorts, sketch_sorts
@@ -24,11 +24,6 @@ from repro.launch.quantile_service import (ingest_dispatches,
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
-
-def _ctx(dtype):
-    from jax.experimental import enable_x64
-    import contextlib
-    return enable_x64() if needs_x64(dtype) else contextlib.nullcontext()
 
 
 def _batched_and_loop(streams, eps=0.05, **kw):
@@ -53,7 +48,7 @@ class TestBatchedIngestParity:
         """Grid cell: ragged per-tick batches through the slot table must
         answer bit-identically to the pre-refactor-shaped per-stream loop
         AND to the np.partition oracle."""
-        with _ctx(dtype):
+        with x64_ctx(dtype):
             streams = {
                 f"t{i}": ragged_chunks(make_case(dist, dtype, 512, seed=i),
                                        3, seed=i)

@@ -20,21 +20,14 @@ The tentpole claims, each pinned here:
     change, a small halflife tracks the new regime, a huge one the
     all-history mix.
 """
-import contextlib
-
 import numpy as np
 import pytest
 
 from repro.core import reset_sketch_sorts, sketch_sorts
 from repro.launch import QuantileService, Window
 
-from _grid import (DTYPES, DISTRIBUTIONS, QS, make_case, needs_x64,
+from _grid import (DTYPES, DISTRIBUTIONS, QS, make_case, x64_ctx,
                    oracle_kth, target_rank)
-
-
-def _ctx(dtype):
-    from jax.experimental import enable_x64
-    return enable_x64() if needs_x64(dtype) else contextlib.nullcontext()
 
 
 def _tick_chunks(dist, dtype, ticks, seed=0):
@@ -58,7 +51,7 @@ class TestWindowedOracleGrid:
     @pytest.mark.parametrize("dtype", DTYPES)
     @pytest.mark.parametrize("dist", DISTRIBUTIONS)
     def test_tick_windows_bit_exact(self, dist, dtype):
-        with _ctx(dtype):
+        with x64_ctx(dtype):
             chunks = _tick_chunks(dist, dtype, ticks=14, seed=3)
             svc = QuantileService(eps=0.05, dtype=dtype,
                                   window_ticks=8, window_subs=4)
@@ -74,7 +67,7 @@ class TestWindowedOracleGrid:
     @pytest.mark.parametrize("dtype", DTYPES)
     @pytest.mark.parametrize("dist", DISTRIBUTIONS)
     def test_count_windows_bit_exact(self, dist, dtype):
-        with _ctx(dtype):
+        with x64_ctx(dtype):
             chunks = _tick_chunks(dist, dtype, ticks=14, seed=7)
             svc = QuantileService(eps=0.05, dtype=dtype,
                                   window_ticks=8, window_subs=4)
