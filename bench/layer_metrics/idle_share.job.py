@@ -1,0 +1,7 @@
+"""Percent of the traced window in which the chip ran no operation, in the
+job cell."""
+from bench import trace
+
+
+def read(r):
+    return None if r.trace is None else trace.idle_share(r.trace)
