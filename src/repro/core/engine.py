@@ -35,6 +35,7 @@ over these plans — signatures and semantics unchanged.
 """
 from __future__ import annotations
 
+import functools
 import math
 from typing import Sequence
 
@@ -153,6 +154,17 @@ def _pmax_pair(priority: jax.Array, value: jax.Array, axis: str):
 # ---------------------------------------------------------------------------
 
 
+def _scoped(phase):
+    """Run ``phase`` under ``jax.named_scope(<its name>)``: compile-time
+    metadata that names every operation the phase adds to a program."""
+    @functools.wraps(phase)
+    def wrapper(*args, **kwargs):
+        with jax.named_scope(phase.__name__):
+            return phase(*args, **kwargs)
+    return wrapper
+
+
+@_scoped
 def phase_sketch(x_local: jax.Array, *, axis: str, num_shards: int, n: int,
                  eps: float):
     """Action 1 (collect sketches): per-shard sorted stride-m summary,
@@ -167,6 +179,7 @@ def phase_sketch(x_local: jax.Array, *, axis: str, num_shards: int, n: int,
     return g_vals, g_wts, m
 
 
+@_scoped
 def phase_pivot(g_vals: jax.Array, g_wts: jax.Array, ks: jax.Array, *,
                 num_shards: int, m: int) -> jax.Array:
     """Replicated pivot selection: query the merged summary for every target
@@ -176,6 +189,7 @@ def phase_pivot(g_vals: jax.Array, g_wts: jax.Array, ks: jax.Array, *,
         lambda k: query_merged_sketch(g_vals, g_wts, k, num_shards, m))(ks)
 
 
+@_scoped
 def phase_count(x_local: jax.Array, pivot: jax.Array, *, axis: str,
                 count3_fn=None, collect: str = "psum") -> jax.Array:
     """Action 2 (collect counts) for a single pivot: per-shard 3-way counts
@@ -188,6 +202,7 @@ def phase_count(x_local: jax.Array, pivot: jax.Array, *, axis: str,
     return jax.lax.all_gather(c, axis).sum(0, dtype=jnp.int32)
 
 
+@_scoped
 def phase_count_extract(x_local: jax.Array, pivots: jax.Array, cap: int, *,
                         axis: str, fused_fn=None, count_extract_fn=None):
     """Actions 2+3's per-shard work, speculative two-sided form: 3-way
@@ -209,6 +224,7 @@ def phase_count_extract(x_local: jax.Array, pivots: jax.Array, cap: int, *,
     return counts, below, above
 
 
+@_scoped
 def phase_reduce(below: jax.Array, above: jax.Array, *, axis: str,
                  num_shards: int, strategy: str = "tree"):
     """Action 3 (treeReduce candidates): both (Q, cap) buffers cross shards
@@ -225,6 +241,7 @@ def phase_reduce(below: jax.Array, above: jax.Array, *, axis: str,
     return below, above
 
 
+@_scoped
 def phase_resolve(pivots: jax.Array, ks: jax.Array, counts: jax.Array,
                   below: jax.Array, above: jax.Array, cap: int) -> jax.Array:
     """Final rank arithmetic (paper Steps 5+9), vmapped over the Q levels;
@@ -345,17 +362,22 @@ def gk_select_sharded(x_local: jax.Array, *, q: float, eps: float, axis: str,
     # ---- Phase 3: one-sided extraction (sign-folded for static shapes) ----
     # For the left side we negate values so "smallest above -pivot" ==
     # "largest below pivot"; extraction volume stays 1x (paper-faithful).
-    y = jnp.where(go_left, -x_local, x_local)
-    piv = jnp.where(go_left, -pivot, pivot)
-    cand = ex_above(y, piv, cap)           # cap smallest of y above piv
-    if reduce_strategy == "tree":
-        cand = tree_reduce_candidates(cand, axis, num_shards, keep_largest=False)
-    else:
-        cand = gather_candidates(cand, axis)
-    need = jnp.maximum(jnp.where(go_left, need_left, need_right), 1)
-    kth = local_ops.kth_smallest(cand, need, cap)
-    side_val = jnp.where(go_left, -kth, kth)
-    return jnp.where((need_left <= 0) & (need_right <= 0), pivot, side_val)
+    with jax.named_scope("phase_extract"):
+        y = jnp.where(go_left, -x_local, x_local)
+        piv = jnp.where(go_left, -pivot, pivot)
+        cand = ex_above(y, piv, cap)       # cap smallest of y above piv
+    with jax.named_scope("phase_reduce"):
+        if reduce_strategy == "tree":
+            cand = tree_reduce_candidates(cand, axis, num_shards,
+                                          keep_largest=False)
+        else:
+            cand = gather_candidates(cand, axis)
+    with jax.named_scope("phase_resolve"):
+        need = jnp.maximum(jnp.where(go_left, need_left, need_right), 1)
+        kth = local_ops.kth_smallest(cand, need, cap)
+        side_val = jnp.where(go_left, -kth, kth)
+        return jnp.where((need_left <= 0) & (need_right <= 0), pivot,
+                         side_val)
 
 
 def approx_quantile_sharded(x_local: jax.Array, *, q: float, eps: float,

@@ -13,6 +13,7 @@ from typing import Tuple
 import jax
 import jax.numpy as jnp
 
+from .. import obs
 from . import lowering
 
 
@@ -61,7 +62,11 @@ def reject_nans(x: jax.Array, where: str) -> None:
     x = jnp.asarray(x)
     if not jnp.issubdtype(x.dtype, jnp.floating):
         return
-    if bool(jnp.any(jnp.isnan(x))):
+    with obs.span("nan_check", where=where):
+        found = jnp.any(jnp.isnan(x))
+        with obs.span("read"):
+            found = bool(found)
+    if found:
         raise ValueError(
             f"{where}: input contains NaN — quantiles are undefined over a "
             f"non-total order (NaN policy: reject; see DESIGN.md §7)")

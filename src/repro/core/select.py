@@ -24,6 +24,7 @@ from typing import Sequence
 import jax
 import jax.numpy as jnp
 
+from .. import obs
 from . import local_ops, lowering
 from .sketch import local_sample_sketch, query_merged_sketch, sample_sketch_params
 
@@ -32,8 +33,10 @@ def _pivot_from_sample_sketch(parts: jax.Array, k: jax.Array, eps: float) -> jax
     P, n_i = parts.shape
     n = P * n_i
     m, s = sample_sketch_params(n, n_i, eps, P)
-    vals, weights = jax.vmap(lambda x: local_sample_sketch(x, m, s))(parts)
-    return query_merged_sketch(vals.ravel(), weights.ravel(), k, P, m)
+    with jax.named_scope("phase_sketch"):
+        vals, weights = jax.vmap(lambda x: local_sample_sketch(x, m, s))(parts)
+    with jax.named_scope("phase_pivot"):
+        return query_merged_sketch(vals.ravel(), weights.ravel(), k, P, m)
 
 
 @functools.partial(jax.jit, static_argnames=("q", "eps", "speculative",
@@ -58,6 +61,11 @@ def _gk_select_jit(parts: jax.Array, q: float, *, eps: float = 0.01,
     Pallas on TPU, jitted jnp fallback on CPU — see
     ``kernels.dispatch.select_backend``) and is ignored without
     ``block_select``.
+
+    Each round runs under a ``jax.named_scope`` (``phase_sketch``,
+    ``phase_pivot``, ``phase_count``, ``phase_extract``,
+    ``phase_count_extract``, ``phase_resolve``): compile-time metadata
+    that names every operation of the compiled program by its round.
     """
     P, n_i = parts.shape
     n = P * n_i
@@ -75,43 +83,57 @@ def _gk_select_jit(parts: jax.Array, q: float, *, eps: float = 0.01,
         # HBM->VMEM sweep.  (Lazy import: core stays usable without the
         # kernels layer.)
         from ..kernels import ops as kernel_ops
-        counts, below, above = jax.vmap(
-            lambda x: kernel_ops.fused_count_extract(
-                x, pivot, cap, backend=backend))(parts)
-        counts = counts.sum(0)
-        return local_ops.resolve(pivot, k, counts[0], counts[1],
-                                 below, above, cap)
+        with jax.named_scope("phase_count_extract"):
+            counts, below, above = jax.vmap(
+                lambda x: kernel_ops.fused_count_extract(
+                    x, pivot, cap, backend=backend))(parts)
+            counts = counts.sum(0)
+        with jax.named_scope("phase_resolve"):
+            return local_ops.resolve(pivot, k, counts[0], counts[1],
+                                     below, above, cap)
 
     if speculative:
         # ---- Rounds 2+3 fused: count and two-sided extraction in one
         # logical phase (still 3 jnp streams; block_select=True is the
         # 1-stream kernel version).
-        counts, below, above = jax.vmap(
-            lambda x: local_ops.fused_count_extract(x, pivot, cap))(parts)
-        counts = counts.sum(0)
-        lt, eq = counts[0], counts[1]
-        return local_ops.resolve(pivot, k, lt, eq, below, above, cap)
+        with jax.named_scope("phase_count_extract"):
+            counts, below, above = jax.vmap(
+                lambda x: local_ops.fused_count_extract(x, pivot, cap))(parts)
+            counts = counts.sum(0)
+            lt, eq = counts[0], counts[1]
+        with jax.named_scope("phase_resolve"):
+            return local_ops.resolve(pivot, k, lt, eq, below, above, cap)
 
     # ---- Round 2: counts -> Delta_k (Steps 4-6) ----
-    counts = jax.vmap(lambda x: local_ops.count3(x, pivot))(parts).sum(0)
-    lt, eq = counts[0], counts[1]
-    need_left = lt - k + 1
-    need_right = k - (lt + eq)
+    with jax.named_scope("phase_count"):
+        counts = jax.vmap(lambda x: local_ops.count3(x, pivot))(parts).sum(0)
+        lt, eq = counts[0], counts[1]
+        need_left = lt - k + 1
+        need_right = k - (lt + eq)
 
     # ---- Round 3: one-sided extraction + reduce (Steps 7-9) ----
     # Paper semantics: only the deficient side is scanned.  Static shapes force
     # both branches to exist in the graph; lax.cond keeps only one side's
     # compute live per invocation.
     def left_branch(_):
-        below = jax.vmap(lambda x: local_ops.extract_below(x, pivot, cap))(parts)
-        return local_ops.kth_largest(below, jnp.maximum(need_left, 1), cap)
+        with jax.named_scope("phase_extract"):
+            below = jax.vmap(
+                lambda x: local_ops.extract_below(x, pivot, cap))(parts)
+        with jax.named_scope("phase_resolve"):
+            return local_ops.kth_largest(below, jnp.maximum(need_left, 1), cap)
 
     def right_branch(_):
-        above = jax.vmap(lambda x: local_ops.extract_above(x, pivot, cap))(parts)
-        return local_ops.kth_smallest(above, jnp.maximum(need_right, 1), cap)
+        with jax.named_scope("phase_extract"):
+            above = jax.vmap(
+                lambda x: local_ops.extract_above(x, pivot, cap))(parts)
+        with jax.named_scope("phase_resolve"):
+            return local_ops.kth_smallest(above, jnp.maximum(need_right, 1),
+                                          cap)
 
     side_val = jax.lax.cond(need_left > 0, left_branch, right_branch, operand=None)
-    return jnp.where((need_left <= 0) & (need_right <= 0), pivot, side_val)
+    with jax.named_scope("phase_resolve"):
+        return jnp.where((need_left <= 0) & (need_right <= 0), pivot,
+                         side_val)
 
 
 def gk_select(parts: jax.Array, q: float, *, eps: float = 0.01,
@@ -136,11 +158,14 @@ def gk_select(parts: jax.Array, q: float, *, eps: float = 0.01,
     ``kernels.dispatch.Backend``) picks the kernel implementation when
     ``block_select=True``; None selects per platform at trace time.
     """
-    if check_nans:
-        local_ops.reject_nans(parts, "gk_select")
-    return lowering.call(_gk_select_jit, parts, q, eps=eps,
-                         speculative=speculative, block_select=block_select,
-                         k=k, backend=backend)
+    with obs.span("gk_select"):
+        if check_nans:
+            local_ops.reject_nans(parts, "gk_select")
+        with obs.span("dispatch"):
+            return lowering.call(_gk_select_jit, parts, q, eps=eps,
+                                 speculative=speculative,
+                                 block_select=block_select, k=k,
+                                 backend=backend)
 
 
 def exact_quantile(x: jax.Array, q: float, *, eps: float = 0.01,
@@ -190,32 +215,39 @@ def _gk_select_multi_jit(parts: jax.Array, qs: tuple, *, eps: float = 0.01,
     ks = jnp.array([local_ops.target_rank(n, q) for q in qs], jnp.int32)
 
     m, s = sample_sketch_params(n, n_i, eps, P)
-    vals, weights = jax.vmap(lambda x: local_sample_sketch(x, m, s))(parts)
+    with jax.named_scope("phase_sketch"):
+        vals, weights = jax.vmap(lambda x: local_sample_sketch(x, m, s))(parts)
     fv, fw = vals.ravel(), weights.ravel()
-    pivots = jax.vmap(lambda k: query_merged_sketch(fv, fw, k, P, m))(ks)
+    with jax.named_scope("phase_pivot"):
+        pivots = jax.vmap(lambda k: query_merged_sketch(fv, fw, k, P, m))(ks)
 
     cap = local_ops.candidate_cap(n, eps, n_i)
 
     if block_select:
         from ..kernels import ops as kernel_ops
-        counts, below, above = jax.vmap(
-            lambda x: kernel_ops.fused_count_extract_multi(
-                x, pivots, cap, backend=backend))(parts)
-        counts = counts.sum(0)                     # (Q, 3)
-        below = jnp.swapaxes(below, 0, 1)          # (P, Q, cap) -> (Q, P, cap)
-        above = jnp.swapaxes(above, 0, 1)
+        with jax.named_scope("phase_count_extract"):
+            counts, below, above = jax.vmap(
+                lambda x: kernel_ops.fused_count_extract_multi(
+                    x, pivots, cap, backend=backend))(parts)
+            counts = counts.sum(0)                 # (Q, 3)
 
         def resolve_one(pivot, k, c, b, a):
             return local_ops.resolve(pivot, k, c[0], c[1], b, a, cap)
 
-        return jax.vmap(resolve_one)(pivots, ks, counts, below, above)
+        with jax.named_scope("phase_resolve"):
+            below = jnp.swapaxes(below, 0, 1)      # (P, Q, cap) -> (Q, P, cap)
+            above = jnp.swapaxes(above, 0, 1)
+            return jax.vmap(resolve_one)(pivots, ks, counts, below, above)
 
     def one(pk):
         pivot, k = pk
-        counts, below, above = jax.vmap(
-            lambda x: local_ops.fused_count_extract(x, pivot, cap))(parts)
-        counts = counts.sum(0)
-        return local_ops.resolve(pivot, k, counts[0], counts[1], below, above, cap)
+        with jax.named_scope("phase_count_extract"):
+            counts, below, above = jax.vmap(
+                lambda x: local_ops.fused_count_extract(x, pivot, cap))(parts)
+            counts = counts.sum(0)
+        with jax.named_scope("phase_resolve"):
+            return local_ops.resolve(pivot, k, counts[0], counts[1], below,
+                                     above, cap)
 
     # one level at a time: a vmap over the Q pivots would hold Q masked
     # copies of the whole input at once (Q x 4 GB at the paper's 10^9 f32)
@@ -232,8 +264,10 @@ def gk_select_multi(parts: jax.Array, qs: tuple, *, eps: float = 0.01,
     ``check_nans=False`` opts out (see ``gk_select``).  ``backend`` picks
     the kernel implementation when ``block_select=True`` (see
     ``gk_select``)."""
-    if check_nans:
-        local_ops.reject_nans(parts, "gk_select_multi")
-    return lowering.call(_gk_select_multi_jit, parts, tuple(qs), eps=eps,
-                         speculative=speculative, block_select=block_select,
-                         backend=backend)
+    with obs.span("gk_select_multi"):
+        if check_nans:
+            local_ops.reject_nans(parts, "gk_select_multi")
+        with obs.span("dispatch"):
+            return lowering.call(_gk_select_multi_jit, parts, tuple(qs),
+                                 eps=eps, speculative=speculative,
+                                 block_select=block_select, backend=backend)

@@ -28,46 +28,41 @@ from __future__ import annotations
 
 import dataclasses
 import math
-import threading
 from typing import NamedTuple, Tuple
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
+from .. import obs
+
 
 # ---------------------------------------------------------------------------
-# sketch-phase sort accounting (mirrors kernels.ops' HBM-pass counter).
+# sketch-phase sort accounting: the ``sketch.sorts`` counter of
+# ``repro.obs``, read by the tests (a warm exact query ticks it ZERO times).
 # Ticked at the DISPATCH layer only — QuantileService.ingest / the cold
 # rebuild — never inside traced code, so the count is exact per eager call
 # (a trace-time tick would double-count the first call of each shape).
-# benchmarks/bench_service.py asserts a warm exact query ticks this ZERO times.
-# Guarded by a lock: with threaded ingest workers (launch/ingest_pool.py) the
-# bare `dict[k] += n` read-modify-write races and silently drops ticks,
-# which would let the bench/test assertions pass on a wrong count.
 # ---------------------------------------------------------------------------
 
-_SKETCH_SORTS = {"total": 0}
-_SKETCH_SORTS_LOCK = threading.Lock()
+SKETCH_SORTS = "sketch.sorts"
 
 
 def reset_sketch_sorts() -> None:
     """Zero the sketch-phase sort counter."""
-    with _SKETCH_SORTS_LOCK:
-        _SKETCH_SORTS["total"] = 0
+    obs.reset(SKETCH_SORTS)
 
 
 def sketch_sorts() -> int:
     """Sketch-construction sorts dispatched since the last reset."""
-    with _SKETCH_SORTS_LOCK:
-        return _SKETCH_SORTS["total"]
+    return obs.counters().get(SKETCH_SORTS, 0)
 
 
 def record_sketch_sort(n: int = 1) -> None:
     """Tick the sketch-phase sort counter (called by every code path that
     sorts raw data to build or rebuild a sketch).  Thread-safe."""
-    with _SKETCH_SORTS_LOCK:
-        _SKETCH_SORTS["total"] += n
+    obs.count(SKETCH_SORTS, n)
+
 
 # ---------------------------------------------------------------------------
 # TPU-native sample sketch (pure jnp; used inside jit / shard_map)

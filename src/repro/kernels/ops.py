@@ -28,18 +28,18 @@ Every wrapper is a plain Python function that bumps the module HBM-pass
 counter once per full-array stream *the selected backend actually
 dispatches* — 1 for a fused Pallas sweep, 3 per pivot for the jnp oracle
 (count + 2x top_k streams), 3*G*Q for the segmented oracle — and then
-executes.  The counter counts eager dispatches — exactly what
-``benchmarks/bench_fused.py`` measures; calls traced inside an outer jit
-tick once at trace time and are not the counter's job.
+executes.  The counter (``kernels.hbm_passes`` in ``repro.obs``) counts
+eager dispatches; calls traced inside an outer jit tick once at trace
+time and are not the counter's job.
 """
 from __future__ import annotations
 
 import functools
-import threading
 
 import jax
 import jax.numpy as jnp
 
+from .. import obs
 from . import dispatch, ref
 from .dispatch import JNP
 from .partition_count import LANES, partition_count
@@ -47,32 +47,26 @@ from .fused_select import byte_histogram as _byte_histogram_kernel  # noqa: F401
 
 
 # ---------------------------------------------------------------------------
-# HBM pass accounting (the bandwidth-bound cost model; see DESIGN.md §2)
+# HBM pass accounting (the bandwidth-bound cost model; see DESIGN.md §2): the
+# ``kernels.hbm_passes`` counter of ``repro.obs``, whose lock keeps the
+# count exact under concurrent ingest/query threads (launch/ingest_pool.py)
 # ---------------------------------------------------------------------------
 
-# Lock-guarded: concurrent ingest/query threads (launch/ingest_pool.py) all
-# route through these wrappers, and the bare `dict[k] += n` read-modify-write
-# would drop ticks under contention — a silently-wrong pass count is worse
-# than none, because the benches ASSERT on it.
-_HBM_PASSES = {"total": 0}
-_HBM_LOCK = threading.Lock()
+HBM_PASSES = "kernels.hbm_passes"
 
 
 def reset_hbm_passes() -> None:
     """Zero the full-array streaming-pass counter."""
-    with _HBM_LOCK:
-        _HBM_PASSES["total"] = 0
+    obs.reset(HBM_PASSES)
 
 
 def hbm_passes() -> int:
     """Full-array HBM streaming passes dispatched since the last reset."""
-    with _HBM_LOCK:
-        return _HBM_PASSES["total"]
+    return obs.counters().get(HBM_PASSES, 0)
 
 
 def _tick(n: int = 1) -> None:
-    with _HBM_LOCK:
-        _HBM_PASSES["total"] += n
+    obs.count(HBM_PASSES, n)
 
 
 def _backend(backend, use_pallas: bool):

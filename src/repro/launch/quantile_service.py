@@ -98,6 +98,7 @@ from __future__ import annotations
 import contextlib
 import dataclasses
 import functools
+import itertools
 import math
 import os
 import threading
@@ -107,6 +108,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import obs
 from repro.core import engine, local_ops
 from repro.core.sketch import (SketchState, record_sketch_sort, sketch_budget,
                                sketch_init, sketch_init_stack,
@@ -126,27 +128,26 @@ def _round_up(x: int, multiple: int) -> int:
 # Structural proof obligation for the slot-table refactor: one ingest tick
 # must issue a CONSTANT number of jitted device calls regardless of how many
 # streams it touches (the dict-of-streams design issued O(S)).  Every device
-# dispatch on the ingest path ticks this; bench_service asserts the count is
-# identical at S=100 and S=10^4.  Lock-guarded: with threaded ingest
-# (launch/ingest_pool.py) a bare `+=` drops ticks under contention and the
-# bench assertion would pass on a wrong count.
-_INGEST_DISPATCHES = {"count": 0}
-_INGEST_DISPATCHES_LOCK = threading.Lock()
+# dispatch on the ingest path ticks the ``service.ingest_dispatches``
+# counter of ``repro.obs``; the tests assert the count is the same at any S,
+# and the registry's lock keeps it exact under threaded ingest
+# (launch/ingest_pool.py).
+INGEST_DISPATCHES = "service.ingest_dispatches"
+# A query program built for a candidate cap not seen before: ticked where
+# ``_chunk_fn`` and ``_resolve_fn`` miss their caches.
+CAP_PROGRAMS = "service.cap_programs"
 
 
 def reset_ingest_dispatches() -> None:
-    with _INGEST_DISPATCHES_LOCK:
-        _INGEST_DISPATCHES["count"] = 0
+    obs.reset(INGEST_DISPATCHES)
 
 
 def ingest_dispatches() -> int:
-    with _INGEST_DISPATCHES_LOCK:
-        return _INGEST_DISPATCHES["count"]
+    return obs.counters().get(INGEST_DISPATCHES, 0)
 
 
 def record_ingest_dispatch(n: int = 1) -> None:
-    with _INGEST_DISPATCHES_LOCK:
-        _INGEST_DISPATCHES["count"] += n
+    obs.count(INGEST_DISPATCHES, n)
 
 
 # --- reader-writer lock -----------------------------------------------------
@@ -386,6 +387,7 @@ def _chunk_fn(cap: int, fused: bool, backend=None):
     pivot as a plain operand, so externally-supplied (warm) pivots need no
     retrace.  ``backend`` is the dispatch handle the seam closes over
     (hashable: None / spec string / frozen Backend — safe as an lru key)."""
+    obs.count(CAP_PROGRAMS)
     if fused:
         from repro.kernels import ops as kernel_ops
 
@@ -457,6 +459,8 @@ def _row_chunk_fn(cap: int):
 
 @functools.lru_cache(maxsize=None)
 def _resolve_fn(cap: int):
+    obs.count(CAP_PROGRAMS)
+
     def fn(pivot, k, counts, belows, aboves):
         lt = sum(c[0] for c in counts)
         eq = sum(c[1] for c in counts)
@@ -541,7 +545,8 @@ class QuantileService:
     decode step) trace each phase once and replay it for the service's
     lifetime.  A batched ingest tick touching 10^4 streams issues the same
     constant number of device calls as one touching a single stream
-    (``ingest_dispatches`` counts them; bench_service asserts O(1)).
+    (the ``service.ingest_dispatches`` counter of ``repro.obs`` counts
+    them; the tests assert O(1)).
     """
 
     def __init__(self, *, eps: float = 0.01, budget: Optional[int] = None,
@@ -602,6 +607,7 @@ class QuantileService:
         # queries the read side; worker threads never touch a shared
         # service's lock because they write into private local_buffer()s.
         self._rw = RWLock()
+        self._requests = itertools.count()   # numbers windowed queries
         # --- slot table ---------------------------------------------------
         self._stacked: Optional[SketchState] = None   # leaves (capacity, ...)
         self._names: Dict[str, int] = {}              # name -> slot
@@ -845,8 +851,12 @@ class QuantileService:
         sort, no ring record, no logical-clock advance.  A MIXED tick still
         registers its empty rows' streams (count 0, sketch row untouched).
         """
-        names = list(names)
-        batches = list(batches)
+        with obs.span("service.ingest_batch"):
+            self._ingest_tick(list(names), list(batches), transform,
+                              _nan_checked)
+
+    def _ingest_tick(self, names: List[str], batches: list,
+                     transform: Optional[str], nan_checked: bool) -> None:
         if len(names) != len(batches):
             raise ValueError(f"names/batches length mismatch: "
                              f"{len(names)} vs {len(batches)}")
@@ -871,34 +881,38 @@ class QuantileService:
 
         slots = self._ensure_slots(names)
 
-        if device_in:
-            matrix = _pack_fn(length, self.dtype.name, transform)(*batches)
-            record_ingest_dispatch()    # the one packing dispatch
-        else:
-            hi = _high_sentinel_np(self.dtype)
-            host = np.full((len(batches), length), hi, dtype=self.dtype)
-            for i, b in enumerate(batches):
-                host[i, :lengths[i]] = b
-            matrix = jnp.asarray(host)
-            record_ingest_dispatch()    # the one host->device transfer
+        with obs.span("service.pack"):
+            if device_in:
+                matrix = _pack_fn(length, self.dtype.name, transform)(*batches)
+                record_ingest_dispatch()    # the one packing dispatch
+            else:
+                hi = _high_sentinel_np(self.dtype)
+                host = np.full((len(batches), length), hi, dtype=self.dtype)
+                for i, b in enumerate(batches):
+                    host[i, :lengths[i]] = b
+                matrix = jnp.asarray(host)
+                record_ingest_dispatch()    # the one host->device transfer
         n_valid = np.asarray(lengths, dtype=np.int32)
 
-        if self.check_nans and not _nan_checked:
+        if self.check_nans and not nan_checked:
             local_ops.reject_nans(matrix, "QuantileService.ingest")
 
         tick = self._tick
         record_sketch_sort()            # sketch_update_batch sorts the tick
         record_ingest_dispatch()        # the one batched update dispatch
         if self.window_ticks is not None:
-            sub_slots = self._rotate_subs(slots, n_valid, tick)
-            self._stacked = _update_rows_doubled(
-                self._stacked,
-                jnp.asarray(np.concatenate([slots, sub_slots])),
-                matrix, jnp.asarray(n_valid))
+            with obs.span("service.rotate"):
+                sub_slots = self._rotate_subs(slots, n_valid, tick)
+            with obs.span("service.update"):
+                self._stacked = _update_rows_doubled(
+                    self._stacked,
+                    jnp.asarray(np.concatenate([slots, sub_slots])),
+                    matrix, jnp.asarray(n_valid))
         else:
-            self._stacked = _update_rows(self._stacked,
-                                         jnp.asarray(slots), matrix,
-                                         jnp.asarray(n_valid))
+            with obs.span("service.update"):
+                self._stacked = _update_rows(self._stacked,
+                                             jnp.asarray(slots), matrix,
+                                             jnp.asarray(n_valid))
         for slot, nv in zip(slots, n_valid):
             self._counts[int(slot)] += int(nv)
             self._retained[int(slot)] += int(nv)
@@ -906,7 +920,8 @@ class QuantileService:
                                       n_valid=n_valid, tick=tick))
         self._tick = tick + 1
         if self.window_ticks is not None:
-            self._retire_ring()
+            with obs.span("service.retire"):
+                self._retire_ring()
 
     @_locked("w")
     def ingest_grouped(self, name: str, values, keys) -> None:
@@ -1198,14 +1213,19 @@ class QuantileService:
         simply covers everything and the answer equals ``exact()``), and
         when no value falls inside the window."""
         win = _as_window(window)
-        slot = self._require(name)
-        slices, n_w, start = self._window_slices(name, slot, win)
-        if n_w == 0:
-            raise ValueError(f"stream {name!r} has no values in the window")
-        k = local_ops.target_rank(n_w, q)
-        pivot, bound = self._window_pivot(slot, k, n_w, start, slices)
-        cap = min(n_w, _round_up(bound + 2, 128))
-        return self._count_extract_resolve(slices, n_w, k, pivot, cap)
+        with obs.span("service.windowed", request=next(self._requests)):
+            slot = self._require(name)
+            with obs.span("service.slices"):
+                slices, n_w, start = self._window_slices(name, slot, win)
+            if n_w == 0:
+                raise ValueError(
+                    f"stream {name!r} has no values in the window")
+            k = local_ops.target_rank(n_w, q)
+            with obs.span("service.pivot"):
+                pivot, bound = self._window_pivot(slot, k, n_w, start,
+                                                  slices)
+            cap = min(n_w, _round_up(bound + 2, 128))
+            return self._count_extract_resolve(slices, n_w, k, pivot, cap)
 
     @_locked("r")
     def window_count(self, name: str, *, window) -> int:
@@ -1540,8 +1560,10 @@ class QuantileService:
             lambda a: a[jnp.asarray([s.slot for s in subs])], self._stacked)
         merged = _merge_subs_jit(rows)
         pivot = _query_jit(merged, k + over // 2)
-        bound = int(sketch_rank_bound(merged)) + (over + 1) // 2
-        return pivot, bound
+        bound = sketch_rank_bound(merged)
+        with obs.span("read"):
+            bound = int(bound)
+        return pivot, bound + (over + 1) // 2
 
     def _cold_pivot(self, chunks: List[jax.Array], k: int):
         """The stateless job's action 1: re-sketch every buffered chunk from
@@ -1553,7 +1575,10 @@ class QuantileService:
             record_sketch_sort()
             cold = _update_jit(cold, chunk)
         pivot = _query_jit(cold, k)
-        return pivot, int(sketch_rank_bound(cold))
+        bound = sketch_rank_bound(cold)
+        with obs.span("read"):
+            bound = int(bound)
+        return pivot, bound
 
     def _count_extract_resolve(self, chunks: List[jax.Array], n: int,
                                k: int, pivot, cap: int):
@@ -1562,20 +1587,26 @@ class QuantileService:
         (tracked-bound-violating) pathological case so exactness never
         depends on the stream's history."""
         counts, belows, aboves = [], [], []
-        for chunk in chunks:
-            cap_c = min(chunk.shape[0], cap)
-            c, b, a = _chunk_fn(cap_c, self.fused, self.backend)(chunk, pivot)
-            counts.append(c)
-            belows.append(b)
-            aboves.append(a)
-        out, lt, eq = _resolve_fn(cap)(
-            jnp.asarray(pivot), jnp.int32(k), tuple(counts), tuple(belows),
-            tuple(aboves))
-        need = max(int(lt) - k + 1, k - (int(lt) + int(eq)))
-        if need > cap:     # tracked bound violated — impossible by the
-            # invariant, but exactness must not hinge on it: widen and rerun
-            return self._count_extract_resolve(
-                chunks, n, k, pivot, min(n, _round_up(need + 2, 128)))
+        with obs.span("service.count_extract"):
+            for chunk in chunks:
+                cap_c = min(chunk.shape[0], cap)
+                c, b, a = _chunk_fn(cap_c, self.fused, self.backend)(
+                    chunk, pivot)
+                counts.append(c)
+                belows.append(b)
+                aboves.append(a)
+        with obs.span("service.resolve"):
+            out, lt, eq = _resolve_fn(cap)(
+                jnp.asarray(pivot), jnp.int32(k), tuple(counts),
+                tuple(belows), tuple(aboves))
+            with obs.span("read"):
+                lt, eq = int(lt), int(eq)
+            need = max(lt - k + 1, k - (lt + eq))
+            if need > cap:     # tracked bound violated — impossible by the
+                # invariant, but exactness must not hinge on it: widen and
+                # rerun
+                return self._count_extract_resolve(
+                    chunks, n, k, pivot, min(n, _round_up(need + 2, 128)))
         return out
 
     # -- snapshot / restore -------------------------------------------------
