@@ -131,18 +131,149 @@ def fused_count_extract(x: jax.Array, pivot: jax.Array, cap: int):
             extract_above(x, pivot, cap))
 
 
+# Candidate buffers of at least this many lanes pick their k-th value by
+# order-key bisection; smaller ones sort.  The bisection costs one fused
+# compare-and-sum over the buffer per key bit (32 for 32-bit data) and no
+# copy of it, plus a fixed ~0.05 ms of loop; the sort, up to 4.8 ns a lane.
+# On one v5e, flat float32 (``experiments/kth_crossover.py``, its numbers
+# in PERF.md): at 2^16 lanes the key sort takes 0.049 ms and the bisection
+# 0.065 ms, at 2^17 0.106 ms and 0.080 ms; at 2^30 a sort of the values
+# took 5.19 s and the bisection 0.18 s.
+BISECT_MIN_LANES = 1 << 17
+
+_KEY_UINT = {1: jnp.uint8, 2: jnp.uint16, 4: jnp.uint32, 8: jnp.uint64}
+_KEY_FLOATS = (jnp.float16, jnp.bfloat16, jnp.float32, jnp.float64)
+
+
+def _has_order_key(dtype) -> bool:
+    """True for the dtypes with an order key (``_order_key``): integers of
+    8 to 64 bits and the IEEE-style floats with infinities."""
+    dt = jnp.dtype(dtype)
+    if jnp.issubdtype(dt, jnp.integer):
+        return dt.itemsize in _KEY_UINT
+    return any(dt == f for f in _KEY_FLOATS)
+
+
+def _bisects(cands: jax.Array) -> bool:
+    """The selector a candidate buffer takes, from its shape and dtype
+    alone: bisection from ``BISECT_MIN_LANES`` lanes (int32 counts bound it
+    below 2^31 lanes), the sort below that and for dtypes with no order
+    key."""
+    return (BISECT_MIN_LANES <= cands.size < 2 ** 31
+            and _has_order_key(cands.dtype))
+
+
+def _order_key(x: jax.Array) -> jax.Array:
+    """The order key of ``x``: the unsigned integer of its width that
+    orders as the values do.  Integers are offset by the sign bit; floats
+    are sign-flipped (a positive gains the top bit, a negative is
+    complemented), -0.0 taking +0.0's key, as ``jnp.sort`` ties them.
+    Integer compares of keys rank subnormals as IEEE does, where a float
+    compare may flush them to zero (XLA's CPU sort does)."""
+    dt = x.dtype
+    u = _KEY_UINT[dt.itemsize]
+    top = u(1 << (8 * dt.itemsize - 1))
+    b = jax.lax.bitcast_convert_type(x, u)
+    if jnp.issubdtype(dt, jnp.floating):
+        b = jnp.where(b == top, u(0), b)
+        return jnp.where(b >= top, ~b, b | top)
+    if jnp.issubdtype(dt, jnp.signedinteger):
+        return b ^ top
+    return b
+
+
+def _from_key(key: jax.Array, dtype) -> jax.Array:
+    """The value of ``dtype`` whose order key is ``key``."""
+    dt = jnp.dtype(dtype)
+    top = key.dtype.type(1 << (8 * dt.itemsize - 1))
+    if jnp.issubdtype(dt, jnp.floating):
+        return jax.lax.bitcast_convert_type(
+            jnp.where(key >= top, key ^ top, ~key), dt)
+    if jnp.issubdtype(dt, jnp.signedinteger):
+        return jax.lax.bitcast_convert_type(key ^ top, dt)
+    return key
+
+
+def _count_below_key(cands: jax.Array, key: jax.Array) -> jax.Array:
+    """#{c in cands : order key of c < key}: one compare-and-sum of the
+    buffer against scalars, so no key of the buffer is made.
+
+    Integers compare their values with the value of ``key``.  Floats
+    compare their bits as signed integers ``s``, which order positives as
+    values do, and negatives (but -0.0, ``s == int min``) in reverse: a
+    ``key`` in the positive half counts every negative and the positives
+    with ``s`` below ``key``'s magnitude, -0.0 once that magnitude is past
+    +0.0; one in the negative half counts the negatives with ``s`` above
+    its complement.  So the count is exact for subnormals too (a float
+    compare may flush them to zero)."""
+    dt = cands.dtype
+    if not jnp.issubdtype(dt, jnp.floating):
+        return jnp.sum(cands < _from_key(key, dt), dtype=jnp.int32)
+    sint = jnp.dtype(f"int{8 * dt.itemsize}")
+    smin = jnp.iinfo(sint).min
+    top = key.dtype.type(1 << (8 * dt.itemsize - 1))
+    mag = jax.lax.bitcast_convert_type(key ^ top, sint)   # >= 0 when positive
+    comp = jax.lax.bitcast_convert_type(~key, sint)       # < 0 when negative
+    positive = key >= top
+    lo = jnp.where(positive, jnp.where(mag == 0, smin + 1, smin),
+                   comp + 1).astype(sint)
+    hi = jnp.where(positive, mag, 0).astype(sint)
+    # tied to the loop-variant bounds, the bitcast stays in the pass's
+    # fusion: hoisted out of the loop it would be a copy of the buffer
+    cands, lo = jax.lax.optimization_barrier((cands, lo))
+    s = jax.lax.bitcast_convert_type(cands, sint)
+    return jnp.sum((s >= lo) & (s < hi), dtype=jnp.int32)
+
+
+def _kth_bisect(cands: jax.Array, k: jax.Array) -> jax.Array:
+    """k-th smallest (1-based, k in [1, cands.size]) of any-shape ``cands``
+    by bisection on the order key, from the high bit down: a bit is kept
+    when fewer than k candidates have a key below the prefix with it set,
+    so the prefix ends as the least key t with #{key <= t} >= k, which is
+    the sort's k-th key.  One ``_count_below_key`` pass a key bit.  On a
+    run of equal keys the value returned equals (``==``) the sort's; for a
+    run of zeros it is +0.0."""
+    dt = cands.dtype
+    u = _KEY_UINT[dt.itemsize]
+    bits = 8 * dt.itemsize
+
+    def step(i, prefix):
+        trial = prefix | (u(1) << (bits - 1 - i).astype(u))
+        return jnp.where(_count_below_key(cands, trial) < k, trial, prefix)
+
+    with jax.named_scope("kth_bisect"):
+        key = jax.lax.fori_loop(0, bits, step, jnp.zeros((), u))
+        return _from_key(key, dt)
+
+
+def _kth_sort(cands: jax.Array, k: jax.Array) -> jax.Array:
+    """k-th smallest (1-based, k in [1, cands.size]) by a sort of the
+    buffer's order keys, or of its values for a dtype with none."""
+    if not _has_order_key(cands.dtype):
+        return jnp.sort(cands.ravel())[k - 1]
+    keys = jnp.sort(_order_key(cands).ravel())
+    return _from_key(keys[k - 1], cands.dtype)
+
+
 def kth_smallest(cands: jax.Array, k: jax.Array, cap: int) -> jax.Array:
-    """k-th smallest (1-based, traced k) among candidate lanes; invalid lanes
-    must be +sentinel so they sort last."""
-    srt = jnp.sort(cands.ravel())
-    idx = jnp.clip(k.astype(jnp.int32) - 1, 0, srt.size - 1)
-    return srt[idx]
+    """k-th smallest (1-based, traced k, clamped to [1, cands.size]) among
+    candidate lanes of any shape; invalid lanes must be +sentinel so they
+    rank last.  Large buffers (``_bisects``) take the order-key bisection,
+    32 streaming passes for 32-bit data where a sort would reorder every
+    lane; small ones, where the loop's fixed cost outweighs the sort,
+    sort.  Both return the same value: on a run of zeros, +0.0."""
+    k = jnp.clip(jnp.asarray(k).astype(jnp.int32), 1, cands.size)
+    return (_kth_bisect if _bisects(cands) else _kth_sort)(cands, k)
 
 
 def kth_largest(cands: jax.Array, k: jax.Array, cap: int) -> jax.Array:
-    srt = jnp.sort(cands.ravel())[::-1]
-    idx = jnp.clip(k.astype(jnp.int32) - 1, 0, srt.size - 1)
-    return srt[idx]
+    """k-th largest (1-based, traced k, clamped to [1, cands.size]); invalid
+    lanes must be -sentinel.  The (size - k + 1)-th smallest, by the same
+    selector as ``kth_smallest``: no value is negated, so integer minima
+    are safe."""
+    k = jnp.clip(jnp.asarray(k).astype(jnp.int32), 1, cands.size)
+    return (_kth_bisect if _bisects(cands) else _kth_sort)(
+        cands, cands.size + 1 - k)
 
 
 def target_rank(n: int, q: float) -> int:
@@ -259,6 +390,10 @@ def resolve(pivot: jax.Array, k: jax.Array, lt: jax.Array, eq: jax.Array,
     below: merged candidates < pivot, descending-sorted semantics with
            -sentinel padding (any layout; only rank arithmetic is used).
     above: merged candidates > pivot with +sentinel padding.
+
+    Both sides pick their value with ``kth_largest`` / ``kth_smallest``:
+    order-key bisection for buffers of ``BISECT_MIN_LANES`` lanes or more
+    (the paper's job: 1.007e9), a sort of the buffer below that.
     """
     need_left = lt - k + 1          # >0  => answer is need_left-th largest < pivot
     need_right = k - (lt + eq)      # >0  => answer is need_right-th smallest > pivot
