@@ -28,7 +28,9 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
+from .. import obs
 from . import local_ops, lowering
+from .engine import butterfly_steps
 
 # Engine bodies + collective helpers re-exported for compatibility: every
 # pre-refactor import path (benchmarks, tests, downstream code) keeps
@@ -45,6 +47,36 @@ from .engine import (shard_map_compat, tree_reduce_candidates,
 # ---------------------------------------------------------------------------
 # Public API: run over a mesh
 # ---------------------------------------------------------------------------
+
+
+def _count_collectives(x, num_shards: int, cap: int, *, levels: int = 1,
+                       two_sided: bool = False, sketch: bool = True,
+                       reduce_strategy: str = "tree") -> None:
+    """Count, once per eager GK Select job over ``num_shards``, what its
+    plan hands to collectives (``repro.obs``):
+
+      distributed.collectives   the sketch's 2 ``all_gather`` (cold jobs),
+                                the counts' ``psum``, and per candidate
+                                buffer reduced (1 one-sided, 2 two-sided)
+                                one ``ppermute`` a ``butterfly_steps``
+                                step, or one ``all_gather``
+      distributed.reduce_bytes  the bytes of those buffers, (levels, cap)
+                                of ``x``'s dtype, one for each ppermute
+                                step, or for each of the P - 1 chips an
+                                all_gather reaches
+
+    Nothing is counted while ``x`` is traced or the entry is lowered: no
+    job runs then."""
+    if isinstance(x, jax.core.Tracer) or lowering.active():
+        return
+    buffers = 2 if two_sided else 1
+    if reduce_strategy == "tree":
+        reduce_ops = sends = buffers * len(butterfly_steps(num_shards))
+    else:
+        reduce_ops, sends = buffers, buffers * (num_shards - 1)
+    obs.count("distributed.collectives", 2 * sketch + 1 + reduce_ops)
+    obs.count("distributed.reduce_bytes",
+              sends * levels * cap * jnp.dtype(x.dtype).itemsize)
 
 
 @functools.lru_cache(maxsize=128)
@@ -110,12 +142,20 @@ def distributed_quantile(x: jax.Array, q: float, mesh: Mesh, *,
     if fused and method != "gk_select":
         raise ValueError(f"fused=True only applies to method='gk_select', "
                          f"got method={method!r}")
-    if check_nans:
-        local_ops.reject_nans(x, "distributed_quantile")
-    program = _quantile_program(mesh, axis, method, float(q), eps,
-                                speculative, reduce_strategy, fused,
-                                backend if fused else None)
-    return lowering.call(program, x)
+    with obs.span("distributed_quantile"):
+        if check_nans:
+            local_ops.reject_nans(x, "distributed_quantile")
+        program = _quantile_program(mesh, axis, method, float(q), eps,
+                                    speculative, reduce_strategy, fused,
+                                    backend if fused else None)
+        if method == "gk_select":
+            _count_collectives(
+                x, num_shards,
+                local_ops.candidate_cap(x.size, eps, x.size // num_shards),
+                two_sided=speculative or fused,
+                reduce_strategy=reduce_strategy)
+        with obs.span("dispatch"):
+            return lowering.call(program, x)
 
 
 @functools.lru_cache(maxsize=128)
@@ -172,12 +212,21 @@ def distributed_quantile_multi(x: jax.Array, qs: Sequence[float], mesh: Mesh,
         raise ValueError("distributed_quantile_multi expects a flat array")
     if x.size % num_shards:
         raise ValueError(f"size {x.size} % shards {num_shards} != 0 — pad first")
-    if check_nans:
-        local_ops.reject_nans(x, "distributed_quantile_multi")
-    program = _multi_program(mesh, axis, qs, eps, reduce_strategy, fused,
-                             backend if fused else None, pivots is not None,
-                             None if cap is None else int(cap))
-    if pivots is None:
-        return lowering.call(program, x)
-    return lowering.call(program, x,
-                         jnp.asarray(pivots, x.dtype).reshape(len(qs)))
+    with obs.span("distributed_quantile_multi"):
+        if check_nans:
+            local_ops.reject_nans(x, "distributed_quantile_multi")
+        program = _multi_program(mesh, axis, qs, eps, reduce_strategy, fused,
+                                 backend if fused else None,
+                                 pivots is not None,
+                                 None if cap is None else int(cap))
+        _count_collectives(
+            x, num_shards,
+            local_ops.candidate_cap(x.size, eps, x.size // num_shards)
+            if cap is None else int(cap),
+            levels=len(qs), two_sided=True, sketch=pivots is None,
+            reduce_strategy=reduce_strategy)
+        with obs.span("dispatch"):
+            if pivots is None:
+                return lowering.call(program, x)
+            return lowering.call(
+                program, x, jnp.asarray(pivots, x.dtype).reshape(len(qs)))

@@ -62,6 +62,24 @@ def shard_map_compat(body, *, mesh, in_specs, out_specs):
                          out_specs=out_specs, check_vma=False)
 
 
+def butterfly_steps(num_shards: int) -> list:
+    """The ppermute steps of ``tree_reduce_candidates`` over ``num_shards``,
+    in order, each as its (source, target) pairs: with p2 the largest power
+    of two <= P and r = P - p2, a fold of shards p2.. into 0..r-1 (when
+    r > 0), the log2(p2) XOR steps over shards 0..p2-1, and a broadcast
+    back to the r extra shards (when r > 0).  No step for one shard."""
+    if num_shards <= 1:
+        return []
+    p2 = 1 << (num_shards.bit_length() - 1)
+    r = num_shards - p2
+    steps = [[(i, i ^ (1 << j)) for i in range(p2)]
+             for j in range(p2.bit_length() - 1)]
+    if r:
+        steps = ([[(p2 + i, i) for i in range(r)]] + steps
+                 + [[(i, p2 + i) for i in range(r)]])
+    return steps
+
+
 def tree_reduce_candidates(buf: jax.Array, axis: str, num_shards: int,
                            keep_largest: bool) -> jax.Array:
     """Butterfly reduction of a fixed-capacity candidate buffer, generalized
@@ -103,24 +121,24 @@ def tree_reduce_candidates(buf: jax.Array, axis: str, num_shards: int,
     r = num_shards - p2
     me = jax.lax.axis_index(axis)
     sent_buf = jnp.full(buf.shape, sentinel, buf.dtype)
+    steps = butterfly_steps(num_shards)
 
     if r:
         # fold the r extra shards into shards 0..r-1 (non-destinations
         # receive zeros from ppermute — mask them to identity sentinels)
-        other = jax.lax.ppermute(buf, axis, [(p2 + i, i) for i in range(r)])
+        fold, *steps, back = steps
+        other = jax.lax.ppermute(buf, axis, fold)
         buf = merge(buf, jnp.where(me < r, other, sent_buf))
 
-    for j in range(int(math.log2(p2))):
-        d = 1 << j
-        other = jax.lax.ppermute(buf, axis,
-                                 [(i, i ^ d) for i in range(p2)])
+    for pairs in steps:
+        other = jax.lax.ppermute(buf, axis, pairs)
         if r:
             other = jnp.where(me < p2, other, sent_buf)
         buf = merge(buf, other)
 
     if r:
         # hand the reduced buffer back to the extra shards
-        other = jax.lax.ppermute(buf, axis, [(i, p2 + i) for i in range(r)])
+        other = jax.lax.ppermute(buf, axis, back)
         buf = jnp.where(me >= p2, other, buf)
     return buf
 

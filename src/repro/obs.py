@@ -12,6 +12,8 @@ Names in use (see PERF.md §3 for the metric that reads each):
 
   gk_select, gk_select_multi  the eager entries; children ``nan_check``
                               and ``dispatch`` (the jitted program's call)
+  distributed_quantile,       the sharded eager entries, with the same
+  distributed_quantile_multi  children
   nan_check                   ``local_ops.reject_nans`` (``where=``)
   service.ingest_batch        a tick; children ``service.pack``,
                               ``nan_check``, ``service.rotate``,
@@ -29,6 +31,12 @@ across the service's ingest and query threads.
                               dispatched
   service.ingest_dispatches   device dispatches on the ingest path
   service.cap_programs        query programs built for a new candidate cap
+  distributed.collectives     collectives a sharded GK Select job issues:
+                              the sketch's 2 all_gathers, the counts'
+                              psum, phase_reduce's ppermutes (or
+                              all_gather)
+  distributed.reduce_bytes    bytes of the candidate buffers those
+                              ppermutes carry, a chip's share
 """
 from __future__ import annotations
 
