@@ -97,11 +97,30 @@ def candidate_cap(n_total: int, eps: float, n_local: int) -> int:
     return int(min(n_local, math.ceil(eps * n_total) + 2))
 
 
+# the ``repro.obs`` counter of eager jobs whose extraction takes the whole
+# band (``whole_band``): ticked at dispatch by ``gk_select`` and
+# ``gk_select_multi``, never at trace time
+WHOLE_BAND = "extract.whole_band"
+
+
+def whole_band(cap: int, lanes: int) -> bool:
+    """True when a band of ``cap`` lanes holds every lane of a shard of
+    ``lanes``: every value on the pivot's side is then a candidate, and
+    the band is the masked shard itself, with no selection."""
+    return cap >= lanes
+
+
 def extract_above(x: jax.Array, pivot: jax.Array, cap: int) -> jax.Array:
-    """The ``cap`` smallest values strictly above the pivot, ascending;
-    missing lanes are +sentinel. Paper Step 7, Delta_k > 0 branch
-    (Dutch partition + QuickSelect == masked top-k on TPU)."""
+    """The ``cap`` smallest values strictly above the pivot; missing lanes
+    are +sentinel. Paper Step 7, Delta_k > 0 branch (Dutch partition +
+    QuickSelect == masked top-k on TPU).  Ascending when ``cap`` is below
+    the shard's size; when it covers the shard (``whole_band``) the band
+    is the masked shard in shard order, the same multiset, with no
+    ``top_k``: the resolve reads only ranks."""
     lo, hi = _sentinels(x.dtype)
+    if whole_band(cap, x.shape[-1]):
+        with jax.named_scope("whole_band"):
+            return jnp.where(x > pivot, x, hi)
     keys = jnp.where(x > pivot, x, hi)
     # top_k on negated keys -> k smallest.
     vals, _ = jax.lax.top_k(-keys, cap)
@@ -109,9 +128,14 @@ def extract_above(x: jax.Array, pivot: jax.Array, cap: int) -> jax.Array:
 
 
 def extract_below(x: jax.Array, pivot: jax.Array, cap: int) -> jax.Array:
-    """The ``cap`` largest values strictly below the pivot, descending;
-    missing lanes are -sentinel. Paper Step 7, Delta_k < 0 branch."""
+    """The ``cap`` largest values strictly below the pivot; missing lanes
+    are -sentinel. Paper Step 7, Delta_k < 0 branch.  Descending when
+    ``cap`` is below the shard's size; the masked shard in shard order
+    when it covers it (as ``extract_above``)."""
     lo, hi = _sentinels(x.dtype)
+    if whole_band(cap, x.shape[-1]):
+        with jax.named_scope("whole_band"):
+            return jnp.where(x < pivot, x, lo)
     keys = jnp.where(x < pivot, x, lo)
     vals, _ = jax.lax.top_k(keys, cap)
     return vals
@@ -122,9 +146,11 @@ def fused_count_extract(x: jax.Array, pivot: jax.Array, cap: int):
     plus both capped candidate bands, ``(counts, below, above)``.
 
     This jnp reference implementation still streams the shard three times
-    (count + 2x top_k); ``repro.kernels.ops.fused_count_extract`` is the
-    bit-exact single-HBM-pass drop-in (DESIGN.md §2).  Callers that want
-    kernel injection swap the whole seam, not the three pieces.
+    (count + an extraction a side: a top_k each, or a mask each when the
+    cap covers the shard); ``repro.kernels.ops.fused_count_extract`` is the
+    single-HBM-pass drop-in, its bands the same multisets, sorted
+    (DESIGN.md §2).  Callers that want kernel injection swap the whole
+    seam, not the three pieces.
     """
     return (count3(x, pivot),
             extract_below(x, pivot, cap),

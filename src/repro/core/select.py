@@ -29,6 +29,19 @@ from . import local_ops, lowering
 from .sketch import local_sample_sketch, query_merged_sketch, sample_sketch_params
 
 
+def _count_whole_band(parts, eps: float, block_select: bool) -> None:
+    """Tick ``extract.whole_band`` once for an eager job whose extraction
+    is the masked shard (``local_ops.whole_band``): every path but the
+    kernel layer's (``block_select``).  Nothing is counted while ``parts``
+    is traced or the entry is lowered: no job runs then."""
+    if (block_select or isinstance(parts, jax.core.Tracer)
+            or lowering.active()):
+        return
+    P, n_i = jnp.shape(parts)
+    if local_ops.whole_band(local_ops.candidate_cap(P * n_i, eps, n_i), n_i):
+        obs.count(local_ops.WHOLE_BAND)
+
+
 def _pivot_from_sample_sketch(parts: jax.Array, k: jax.Array, eps: float) -> jax.Array:
     P, n_i = parts.shape
     n = P * n_i
@@ -162,10 +175,12 @@ def gk_select(parts: jax.Array, q: float, *, eps: float = 0.01,
         if check_nans:
             local_ops.reject_nans(parts, "gk_select")
         with obs.span("dispatch"):
-            return lowering.call(_gk_select_jit, parts, q, eps=eps,
-                                 speculative=speculative,
-                                 block_select=block_select, k=k,
-                                 backend=backend)
+            out = lowering.call(_gk_select_jit, parts, q, eps=eps,
+                                speculative=speculative,
+                                block_select=block_select, k=k,
+                                backend=backend)
+        _count_whole_band(parts, eps, block_select)
+        return out
 
 
 def exact_quantile(x: jax.Array, q: float, *, eps: float = 0.01,
@@ -268,6 +283,8 @@ def gk_select_multi(parts: jax.Array, qs: tuple, *, eps: float = 0.01,
         if check_nans:
             local_ops.reject_nans(parts, "gk_select_multi")
         with obs.span("dispatch"):
-            return lowering.call(_gk_select_multi_jit, parts, tuple(qs),
-                                 eps=eps, speculative=speculative,
-                                 block_select=block_select, backend=backend)
+            out = lowering.call(_gk_select_multi_jit, parts, tuple(qs),
+                                eps=eps, speculative=speculative,
+                                block_select=block_select, backend=backend)
+        _count_whole_band(parts, eps, block_select)
+        return out

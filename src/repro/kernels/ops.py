@@ -132,8 +132,10 @@ def extract_above(x: jax.Array, pivot: jax.Array, cap: int) -> jax.Array:
 def fused_count_extract(x: jax.Array, pivot: jax.Array, cap: int, *,
                         use_pallas: bool = True, backend=None):
     """The speculative GK Select round: returns ``(counts, below, above)``
-    with the exact semantics of ``(local_ops.count3,
-    local_ops.extract_below, local_ops.extract_above)``.
+    with the semantics of ``(local_ops.count3, local_ops.extract_below,
+    local_ops.extract_above)``: the same counts and the same multiset in
+    each band, sorted here, where ``local_ops`` leaves a band that covers
+    the whole shard in shard order (the resolve reads only ranks).
 
     On a Pallas backend the shard is read from HBM ONCE (ticks 1); the jnp
     backend really is count + 2x top_k streams and honestly ticks 3."""

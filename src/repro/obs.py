@@ -37,6 +37,10 @@ across the service's ingest and query threads.
                               all_gather)
   distributed.reduce_bytes    bytes of the candidate buffers those
                               ppermutes carry, a chip's share
+  extract.whole_band          eager ``gk_select`` / ``gk_select_multi``
+                              jobs whose extraction is the masked shard,
+                              with no ``top_k`` (the cap covers a
+                              partition)
 """
 from __future__ import annotations
 

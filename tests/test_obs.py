@@ -229,3 +229,35 @@ def test_every_sort_and_top_k_runs_under_a_phase_scope(entry, q, kw):
     assert any(path.endswith("/sort") for _, path in found)
     for name, path in found:
         assert set(path.split("/")) & set(PHASES), (name, path)
+
+
+def test_whole_band_counts_eager_jobs_that_take_the_masked_path():
+    """``extract.whole_band`` ticks once per eager ``gk_select`` /
+    ``gk_select_multi`` job whose cap covers a partition (12 partitions
+    of 512 lanes at eps 0.1), and not for a cap below it (4 partitions),
+    the kernel layer's extraction (``block_select``), a lowering, or a
+    trace."""
+    rng = np.random.default_rng(16)
+    covers = jnp.asarray(rng.normal(size=(12, 512)).astype(np.float32))
+    below = covers[:4]
+    name = "extract.whole_band"
+
+    def ticks(run):
+        obs.reset(name)
+        run()
+        return obs.counters().get(name, 0)
+
+    assert ticks(lambda: gk_select(covers, 0.5, eps=0.1)) == 1
+    assert ticks(lambda: gk_select(covers, 0.5, eps=0.1,
+                                   speculative=True)) == 1
+    assert ticks(lambda: gk_select_multi(covers, (0.1, 0.9), eps=0.1)) == 1
+    assert ticks(lambda: [gk_select(covers, q, eps=0.1)
+                          for q in (0.1, 0.5, 0.9)]) == 3
+    assert ticks(lambda: gk_select(below, 0.5, eps=0.1)) == 0
+    assert ticks(lambda: gk_select_multi(below, (0.1, 0.9), eps=0.1)) == 0
+    assert ticks(lambda: gk_select(covers, 0.5, eps=0.1,
+                                   block_select=True)) == 0
+    assert ticks(lambda: lowering.lower(gk_select, covers, 0.5,
+                                        eps=0.1)) == 0
+    assert ticks(lambda: jax.jit(lambda p: gk_select(p, 0.5, eps=0.1))(
+        covers)) == 0

@@ -8,13 +8,14 @@ The topology is described inside a module fixture (never at import), so
 every xdist worker collects the same tests and only the worker running
 this file loads the TPU compiler."""
 import os
+import re
 
 import jax
 import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
-from repro.core import local_ops
+from repro.core import gk_select, local_ops, lowering
 from repro.kernels import dispatch
 from repro.kernels.band_count import band_count
 from repro.kernels.fused_select import (byte_histogram, fused_select,
@@ -134,3 +135,17 @@ def test_job_resolve_selection(one_chip, select):
     assert " while(" in text and "kth_bisect" in text
     assert " sort(" not in text
     assert compiled.memory_analysis().temp_size_in_bytes < 1 << 20
+
+
+def test_job_extraction_is_a_mask(one_chip):
+    """The paper's job program, (120, 2^23) float32 at q = 0.99 as
+    ``gk_select`` runs it: both branches' extraction is the masked
+    partition under ``whole_band``, with no sort under ``phase_extract``
+    (the parent sorted every partition there)."""
+    compiled = lowering.lower(gk_select, _spec(one_chip, (120, N),
+                                               jnp.float32), 0.99).compile()
+    extract = [line for line in compiled.as_text().splitlines()
+               if re.search(r'op_name="[^"]*phase_extract', line)]
+    assert extract
+    assert not [line for line in extract if re.search(r" sort\(", line)]
+    assert sum("whole_band" in line for line in extract) >= 2
